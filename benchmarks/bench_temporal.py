@@ -1090,12 +1090,21 @@ print(json.dumps({
 """
 
 
-def _comm_mesh_rows() -> dict:
+def _cpu_child_env() -> dict:
+    """Environment of a CPU-emulation child: forced host devices need the
+    CPU backend, and a child that reached for the accelerator would
+    contend with this process, which already holds it."""
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = "src" + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
     )
+    return env
+
+
+def _comm_mesh_rows() -> dict:
+    env = _cpu_child_env()
     r = subprocess.run(
         [sys.executable, "-c", COMM_MESH_SCRIPT], env=env,
         capture_output=True, text=True, timeout=900,
@@ -1120,11 +1129,7 @@ def _cluster_scaling_row() -> dict:
     import tempfile
     import time as _time_mod
 
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["PYTHONPATH"] = "src" + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
-    )
+    env = _cpu_child_env()
     with tempfile.TemporaryDirectory() as td:
         t0 = _time_mod.perf_counter()
         r = subprocess.run(
@@ -1161,11 +1166,7 @@ def _cluster_scaling_row() -> dict:
 
 
 def _mesh_rows() -> dict:
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["PYTHONPATH"] = "src" + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
-    )
+    env = _cpu_child_env()
     r = subprocess.run(
         [sys.executable, "-c", MESH_SCRIPT], env=env, capture_output=True,
         text=True, timeout=900,

@@ -84,6 +84,7 @@ class ClusterGather(CommBackend):
         return jax.pure_callback(
             exchange_fold,
             jax.ShapeDtypeStruct(buf.shape[1:], buf.dtype), buf,
+            vmap_method="sequential",
         )
 
     def any_changed(self, flag: jax.Array) -> jax.Array:
@@ -95,7 +96,8 @@ class ClusterGather(CommBackend):
             return np.asarray(rt.all_reduce_or(bool(f), tag="vote"))
 
         return jax.pure_callback(
-            vote, jax.ShapeDtypeStruct((), jnp.bool_), flag)
+            vote, jax.ShapeDtypeStruct((), jnp.bool_), flag,
+            vmap_method="sequential")
 
     def sum_scalar(self, x: jax.Array) -> jax.Array:
         if not self.runtime.is_distributed:
@@ -110,7 +112,8 @@ class ClusterGather(CommBackend):
             return out
 
         return jax.pure_callback(
-            ssum, jax.ShapeDtypeStruct(x.shape, x.dtype), x)
+            ssum, jax.ShapeDtypeStruct(x.shape, x.dtype), x,
+            vmap_method="sequential")
 
 
 def cluster_comm(runtime: Optional[ClusterRuntime]) -> CommBackend:

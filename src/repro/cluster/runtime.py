@@ -381,36 +381,33 @@ def init_cluster(
     * ``"jax"`` — additionally initialize ``jax.distributed`` against
       ``coordinator`` (real accelerator clusters: gives every process its
       global process index and binds local devices).  The host-lane
-      exchange still rides the TCP port ``coordinator.port + 1``.
-    * ``None``/``"auto"`` — ``"jax"`` when JAX exposes a distributed
-      client, falling back to ``"tcp"`` if its initialization fails
-      (e.g. CPU-only wheels without cross-process support).
+      exchange still rides the TCP port ``coordinator.port + 1``.  A
+      failed initialization raises.
+
+    ``None`` takes ``GOFFISH_TRANSPORT``, else ``"tcp"``.
     """
     coordinator = coordinator or os.environ.get(ENV_COORDINATOR)
     if num_processes is None:
         num_processes = int(os.environ.get(ENV_NUM_PROCESSES, "1"))
     if process_id is None:
         process_id = int(os.environ.get(ENV_PROCESS_ID, "0"))
-    transport = transport or os.environ.get(ENV_TRANSPORT) or "auto"
+    transport = transport or os.environ.get(ENV_TRANSPORT) or "tcp"
+    if transport not in ("tcp", "jax"):
+        raise ValueError(f"transport={transport!r}; pick 'tcp' or 'jax'")
     if num_processes <= 1:
         return ClusterRuntime(0, 1)
     assert coordinator, "multi-process runs need a coordinator host:port"
     host, port = _parse_hostport(coordinator)
 
-    jax_ok = False
-    if transport in ("jax", "auto"):
-        try:
-            import jax
+    jax_ok = transport == "jax"
+    if jax_ok:
+        import jax
 
-            jax.distributed.initialize(
-                coordinator_address=coordinator,
-                num_processes=num_processes,
-                process_id=process_id,
-            )
-            jax_ok = True
-        except Exception:
-            if transport == "jax":
-                raise
+        jax.distributed.initialize(
+            coordinator_address=coordinator,
+            num_processes=num_processes,
+            process_id=process_id,
+        )
     # the host-lane exchange always exists: the boundary fold, the halt
     # vote, and the staging consistency checks ride it even when
     # jax.distributed is up (they are host-side numpy operations)

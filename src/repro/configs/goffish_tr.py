@@ -22,15 +22,18 @@ TR_FULL = GraphConfig(
     cache_slots=14,
 )
 
-# CPU-scale replica preserving the distributional shape (for benchmarks).
+# One-chip replica preserving the distributional shape.  B=128 is the TPU
+# lane width: the fused superstep kernel's tile DMA needs it.  Dense tiles
+# cost ~730 MB per instance at this B, so a 4-instance time pack (the
+# streamed chunk) keeps two chunks in flight well inside 16 GB of HBM.
 TR_SMALL = GraphConfig(
     name="goffish-tr-small",
     num_vertices=16_384,
     avg_degree=2.0,
     num_instances=48,
     num_partitions=8,
-    block_size=64,
-    instances_per_slice=20,
+    block_size=128,
+    instances_per_slice=4,
     bins_per_partition=20,
     cache_slots=14,
 )

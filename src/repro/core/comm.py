@@ -361,8 +361,11 @@ class HostGather(CommBackend):
 
     def combine_boundary(self, buf: jax.Array, sr: Semiring) -> jax.Array:
         fold = _host_fold_sum if sr.name == "plus_mul" else _host_fold_min
+        # "sequential": under the query-axis vmap each source's (P, NB)
+        # buffer is folded by its own call, the same 0..P-1 left fold
         return jax.pure_callback(
-            fold, jax.ShapeDtypeStruct(buf.shape[1:], buf.dtype), buf
+            fold, jax.ShapeDtypeStruct(buf.shape[1:], buf.dtype), buf,
+            vmap_method="sequential",
         )
 
     def any_changed(self, flag: jax.Array) -> jax.Array:

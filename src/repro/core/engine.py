@@ -72,7 +72,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
 from repro.core.blocked import BlockedGraph, SparseBlocked
 from repro.core.comm import CommBackend, make_comm
 from repro.core.ibsp import BSPStats
@@ -755,12 +754,12 @@ class TemporalEngine:
                 lead(1, *q, maxes),     # x0 ([Q,] P, Vp)
             ) + tuple(lead(s.ndim - 1, maxes) for s in self._struct)
         out_specs = (
-            lead(2, *q, iaxis, maxes),  # xs ([Q,] I, P, Vp)
+            lead(1, *q, iaxis, maxes),  # xs ([Q,] I, P, Vp)
             lead(1, *q, maxes),         # final
             lead(1, *q, maxes),         # merged (replicated over data)
             P_(*q, iaxis), P_(*q, iaxis),  # ss, lsw ([Q,] I)
         )
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
@@ -1175,7 +1174,7 @@ class TemporalEngine:
                      occ: Optional[float], warm: bool = False,
                      n_sources: Optional[int] = None) -> EngineResult:
         """Gather device outputs back to global vertex order + stats."""
-        xs, final, merged, ss, lsw = out
+        xs, _, merged, ss, lsw = out
         bg = self.bg
         if self.parts is not None:
             # re-assemble the global partition axis in rank order before
@@ -1185,7 +1184,6 @@ class TemporalEngine:
             # loops lockstep — so they stay local.
             cat = self.cluster.allgather_concat
             xs = cat(np.asarray(xs), axis=-2, tag="gather/xs")
-            final = cat(np.asarray(final), axis=-2, tag="gather/final")
             if pattern == "eventually" and merge == "mean":
                 merged = cat(np.asarray(merged), axis=-2,
                              tag="gather/merged")
@@ -1198,10 +1196,13 @@ class TemporalEngine:
                             for i in range(flat.shape[0])])
             return out.reshape(lead_shape + out.shape[-1:])
 
+        values = gather(xs)
         return EngineResult(
             pattern=pattern,
-            values=gather(xs),
-            final=gather(final),
+            values=values,
+            # the last instance's state: the scan's carry, and (unlike a
+            # data shard's own carry) right when instances are sharded
+            final=values[..., -1, :],
             merged=gather(merged)
             if (pattern == "eventually" and merge == "mean") else None,
             stats={

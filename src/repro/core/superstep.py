@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
 from repro.core.blocked import BlockedGraph
 from repro.core.comm import (  # noqa: F401  (re-exported: historical home)
     Comm,
@@ -304,7 +303,7 @@ def make_spmd_superstep(mesh, sr: Semiring = MIN_PLUS, *,
 
             args = (x, rows, cols, tiles, brows, bcols, btiles,
                     out_slot, out_local, out_mask, vmask)
-            fn = shard_map(
+            fn = jax.shard_map(
                 local_fn, mesh=mesh,
                 in_specs=tuple(lead(a) for a in args),
                 out_specs=lead(x),

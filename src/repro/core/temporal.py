@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
 from repro.core.blocked import BlockedGraph
 from repro.core.comm import make_comm
 from repro.core.superstep import DeviceGraph, pagerank_step
@@ -84,7 +83,7 @@ def make_temporal_runner(
     def spec(*axes):
         return P_(*axes)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(

@@ -40,7 +40,8 @@ warm        collection recorded monotone-improving at deploy AND the
 kernel      jax backend not ``tpu`` -> ``off`` (the jnp oracle path IS
             the lowering — interpreted Pallas on CPU only checks
             semantics, slower than jnp); ``tpu`` + recorded occupancy
-            ``<= 25%`` -> ``fused`` (packed active-tile walk: the fused
+            ``<= 25%`` + a block size that is a multiple of the lane
+            width 128 -> ``fused`` (packed active-tile walk: the fused
             superstep kernel keeps state VMEM-resident, double-buffers
             tile DMA, and folds the halt vote in-kernel); ``tpu``
             otherwise -> ``spmv`` (per-stage SpMV kernel; dense template
@@ -200,6 +201,19 @@ class ExecutionPlan:
             lines.append("  estimates:")
             lines.extend(byte_lines)
         return "\n".join(lines)
+
+
+def staged_bytes_estimate(bg, num_instances: int,
+                          sparse_buckets: Optional[Tuple[int, int]] = None
+                          ) -> int:
+    """Host bytes of one staged batch: dense template tiles, or packed
+    tiles plus their (row, col) index at the given pow2 buckets."""
+    B = bg.block_size
+    if sparse_buckets is None:
+        return int(num_instances * bg.n_parts
+                   * (bg.t_max + bg.tb_max) * B * B * 4)
+    kb, kbb = sparse_buckets
+    return int(num_instances * bg.n_parts * (kb + kbb) * (B * B * 4 + 8))
 
 
 def extend_plan(plan: ExecutionPlan, num_instances: int) -> ExecutionPlan:
@@ -491,7 +505,9 @@ def plan_analytic(
         kn = choice("off", f"jax backend {backend or 'unknown'!s} != tpu — "
                            f"the jnp oracle path is the native lowering; "
                            f"interpreted Pallas only checks semantics")
-    elif occupancy is not None and occupancy <= SPARSE_OCCUPANCY_MAX:
+    elif (occupancy is not None and occupancy <= SPARSE_OCCUPANCY_MAX
+          and bg.block_size % 128 == 0):
+        # the fused kernel's tile DMA compiles only at lane-width blocks
         kn = choice("fused",
                     f"tpu + recorded occupancy {occupancy:.1%} <= "
                     f"{SPARSE_OCCUPANCY_MAX:.0%} — fused superstep kernel "
@@ -523,13 +539,9 @@ def plan_analytic(
         if isinstance(sv, (list, tuple, np.ndarray)):
             n_sources = int(len(sv))
     B = bg.block_size
-    dense_bytes = int(num_instances * bg.n_parts
-                      * (bg.t_max + bg.tb_max) * B * B * 4)
-    sparse_bytes = None
-    if sparse_buckets is not None:
-        kb, kbb = sparse_buckets
-        sparse_bytes = int(num_instances * bg.n_parts
-                           * ((kb + kbb) * (B * B * 4 + 8)))
+    dense_bytes = staged_bytes_estimate(bg, num_instances)
+    sparse_bytes = None if sparse_buckets is None else \
+        staged_bytes_estimate(bg, num_instances, sparse_buckets)
     ex = boundary_exchange_bytes(bg.num_boundary, bg.n_parts, cm.value,
                                  boundary_nnz=nnz)
     source_bytes_delta = None

@@ -56,6 +56,7 @@ array([inf,  0.,  1.,  2.], dtype=float32)
 """
 from __future__ import annotations
 
+import functools
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -64,7 +65,8 @@ import numpy as np
 
 from repro.core.blocked import BlockedGraph, SparseBlocked, pow2_bucket
 from repro.core.engine import EngineResult, RunSpec, TemporalEngine
-from repro.gopher.planner import ExecutionPlan, plan_analytic
+from repro.gopher.planner import (
+    ExecutionPlan, plan_analytic, staged_bytes_estimate)
 from repro.gopher.registry import Analytic, get_analytic
 
 ONES_ATTR = "__ones__"  # pseudo-attribute: unit weights on every edge
@@ -84,6 +86,9 @@ class StagedBatch:
     btiles: Optional[np.ndarray] = None  # dense (I, P, Tb, B, B)
     sp: Optional[SparseBlocked] = None  # sparse packed batch
     nbytes: int = 0
+    # a batch too large to keep resident: each run opens a fresh chunk
+    # stream from the store instead (see GopherSession.cache_staged)
+    stream: Optional[Callable[[], Any]] = None
 
 
 class _StagingCache:
@@ -583,23 +588,8 @@ class GopherSession:
                 # is comparable with the cache path
                 tf = None if transform == "raw" else \
                     (lambda rows: a0.weights(self, rows))
-                if self.cluster is not None:
-                    # shard-local staging: read + fill only this process's
-                    # partition range (delta chains describe the full
-                    # collection, so the shard path stages from the value
-                    # slices); staged_bytes then reports the PER-HOST cost
-                    from repro.cluster.staging import shard_stream
-
-                    stream = shard_stream(
-                        self.store, self.bg, attr, self.cluster,
-                        zero=zero, layout=layout, transform=tf)
-                else:
-                    stream = self.store.load_blocked_stream(
-                        self.bg, attr, zero=zero, layout=layout,
-                        delta=use_delta, transform=tf)
-                cache.staging_passes += 1
-                outs = engine.run_many(
-                    specs, stream=_counted_chunks(stream, cache))
+                outs = engine.run_many(specs, stream=self._store_stream(
+                    cache, attr, zero, layout, use_delta, tf))
             else:
                 # any member analytic materializes the same batch (the
                 # transform rides in the group key)
@@ -833,6 +823,8 @@ class GopherSession:
     def _dispatch_specs(self, engine: TemporalEngine,
                         specs: List[RunSpec],
                         staged: StagedBatch) -> List[EngineResult]:
+        if staged.stream is not None:
+            return engine.run_many(specs, stream=staged.stream())
         if staged.layout == "sparse":
             return engine.run_many(specs, sparse=staged.sp)
         return engine.run_many(specs, tiles=staged.tiles,
@@ -930,9 +922,42 @@ class GopherSession:
         return (a.graph, a.attr, a.transform_name, float(a.zero_fill),
                 layout)
 
+    def _store_stream(self, cache: _StagingCache, attr: str, zero: float,
+                      layout: str, delta: Optional[bool], transform=None):
+        """One counted chunk stream of the template batch from the store."""
+        if self.cluster is not None:
+            # shard-local staging: read + fill only this process's
+            # partition range (delta chains describe the full
+            # collection, so the shard path stages from the value
+            # slices); staged_bytes then reports the PER-HOST cost
+            from repro.cluster.staging import shard_stream
+
+            stream = shard_stream(
+                self.store, self.bg, attr, self.cluster,
+                zero=zero, layout=layout, transform=transform)
+        else:
+            stream = self.store.load_blocked_stream(
+                self.bg, attr, zero=zero, layout=layout,
+                delta=delta, transform=transform)
+        cache.staging_passes += 1
+        return _counted_chunks(stream, cache)
+
     def cache_staged(self, cache: _StagingCache, skey: Tuple,
                      delta: Optional[bool] = None) -> StagedBatch:
         graph, attr, transform, zero, layout = skey
+        if (cache.byte_budget is not None and self.store is not None
+                and transform == "raw" and graph == "template"
+                and attr != ONES_ATTR and skey not in cache.entries):
+            bg = self._blocked(graph)
+            buckets = self.store.sparse_buckets(bg, attr, zero=zero) \
+                if layout == "sparse" else None
+            if staged_bytes_estimate(bg, self.num_instances, buckets) \
+                    > cache.byte_budget:
+                # the cache could never retain it, and materializing it
+                # whole can exceed host and device memory: every run
+                # streams it from the store chunk by chunk instead
+                return StagedBatch(layout=layout, stream=functools.partial(
+                    self._store_stream, cache, attr, zero, layout, delta))
 
         def maker() -> StagedBatch:
             bg = self._blocked(graph)

@@ -35,7 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
+# the MXU multiplies f32 in bf16 passes unless asked for full precision
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _spmv_body(t, cols, tile_ref, x_ref, y_ref, *, sr_name: str, zero: float):
@@ -45,14 +46,15 @@ def _spmv_body(t, cols, tile_ref, x_ref, y_ref, *, sr_name: str, zero: float):
     def _():
         y_ref[...] = jnp.full_like(y_ref, zero)
 
-    xb = x_ref[0]  # (B,)
-    w = tile_ref[0]  # (B, B)
+    xb = x_ref[...]  # (1, B)
+    w = tile_ref[...]  # (B, B)
     if sr_name == "plus_mul":
-        part = jnp.dot(xb, w, preferred_element_type=jnp.float32)
-        y_ref[0, :] = y_ref[0, :] + part
+        # 2-D (1, B) @ (B, B): Mosaic lowers no 1-D dot
+        y_ref[...] += jnp.dot(xb, w, precision=_F32,
+                              preferred_element_type=jnp.float32)
     else:  # min_plus
-        part = jnp.min(xb[:, None] + w, axis=0)
-        y_ref[0, :] = jnp.minimum(y_ref[0, :], part)
+        part = jnp.min(xb.reshape(-1, 1) + w, axis=0, keepdims=True)
+        y_ref[...] = jnp.minimum(y_ref[...], part)
 
 
 def _spmv_kernel(rows, cols, tile_ref, x_ref, y_ref, *, sr_name: str,
@@ -85,7 +87,7 @@ def spmv_blocked_pallas(
     *,
     sr_name: str,
     n_out_blocks: int,
-    interpret: bool = True,
+    interpret: bool = False,
     nnz: jax.Array | None = None,  # () or (1,) int32 valid-tile count
 ) -> jax.Array:
     T, B, _ = tiles.shape
@@ -95,21 +97,25 @@ def spmv_blocked_pallas(
     rows_c = jnp.maximum(rows, 0)  # padding reads block 0, contributes zero
     cols_c = jnp.where(cols < 0, n_out_blocks, cols)  # padding -> dummy block
 
+    # x and y move as (1, B) rows of a (blocks, 1, B) view: the block's
+    # last two dims then equal the array's, which the TPU's (8, 128)
+    # block rule accepts for any row index
     if nnz is None:
         n_prefetch = 2
         prefetch = (rows_c, cols_c)
         kernel = functools.partial(_spmv_kernel, sr_name=sr_name, zero=zero)
-        tile_spec = pl.BlockSpec((1, B, B), lambda t, r, c: (t, 0, 0))
-        x_spec = pl.BlockSpec((1, B), lambda t, r, c: (r[t], 0))
-        out_spec = pl.BlockSpec((1, B), lambda t, r, c: (c[t], 0))
+        tile_spec = pl.BlockSpec((None, B, B), lambda t, r, c: (t, 0, 0))
+        x_spec = pl.BlockSpec((None, 1, B), lambda t, r, c: (r[t], 0, 0))
+        out_spec = pl.BlockSpec((None, 1, B), lambda t, r, c: (c[t], 0, 0))
     else:
         n_prefetch = 3
         prefetch = (rows_c, cols_c, jnp.asarray(nnz, jnp.int32).reshape(1))
         kernel = functools.partial(_spmv_kernel_nnz, sr_name=sr_name,
                                    zero=zero)
-        tile_spec = pl.BlockSpec((1, B, B), lambda t, r, c, n: (t, 0, 0))
-        x_spec = pl.BlockSpec((1, B), lambda t, r, c, n: (r[t], 0))
-        out_spec = pl.BlockSpec((1, B), lambda t, r, c, n: (c[t], 0))
+        tile_spec = pl.BlockSpec((None, B, B), lambda t, r, c, n: (t, 0, 0))
+        x_spec = pl.BlockSpec((None, 1, B), lambda t, r, c, n: (r[t], 0, 0))
+        out_spec = pl.BlockSpec((None, 1, B),
+                                lambda t, r, c, n: (c[t], 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_prefetch,
@@ -120,13 +126,14 @@ def spmv_blocked_pallas(
     y = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_out_blocks + 1, B), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_out_blocks + 1, 1, B),
+                                       jnp.float32),
         interpret=interpret,
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),  # sequential grid: accumulation
         ),
-    )(*prefetch, tiles, x.reshape(nvb, B))
-    y = y[:n_out_blocks]
+    )(*prefetch, tiles, x.reshape(nvb, 1, B))
+    y = y[:n_out_blocks, 0]
     # blocks never touched by a valid tile hold uninitialized memory
     if nnz is None:
         touched = jnp.zeros((n_out_blocks + 1,), jnp.bool_).at[cols_c].set(True)

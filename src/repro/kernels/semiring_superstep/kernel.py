@@ -42,10 +42,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
 from repro.core.semiring import INF
 
 _ZEROS = {"min_plus": INF, "plus_mul": 0.0, "max_plus": -INF}
+# the MXU multiplies f32 in bf16 passes unless asked for full precision
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _fused_kernel(
@@ -112,23 +113,25 @@ def _fused_kernel(
     @pl.when(valid)
     def _():
         r = rows_ref[p, t]
-        xb = x_in_ref[0, r, :]
+        xb = x_in_ref[0, pl.ds(r, 1), :]  # (1, B)
         w = tbuf[slot]
         if sr_name == "plus_mul":
-            y_ref[0, :] = y_ref[0, :] + jnp.dot(
-                xb, w, preferred_element_type=jnp.float32)
+            # 2-D (1, B) @ (B, B): Mosaic lowers no 1-D dot
+            y_ref[...] += jnp.dot(xb, w, precision=_F32,
+                                  preferred_element_type=jnp.float32)
         else:
             # broadcast-add + min-reduce on the VPU (idempotent: exact)
-            y_ref[0, :] = jnp.minimum(
-                y_ref[0, :], jnp.min(xb[:, None] + w, axis=0))
+            y_ref[...] = jnp.minimum(
+                y_ref[...],
+                jnp.min(xb.reshape(-1, 1) + w, axis=0, keepdims=True))
 
     @pl.when(last)
     def _():
-        base = x_comb_ref[0, c, :]
+        base = x_comb_ref[0, pl.ds(c, 1), :]
         if sr_name == "plus_mul":
-            x_out_ref[0, c, :] = base + y_ref[0, :]
+            x_out_ref[0, pl.ds(c, 1), :] = base + y_ref[...]
         else:
-            x_out_ref[0, c, :] = jnp.minimum(base, y_ref[0, :])
+            x_out_ref[0, pl.ds(c, 1), :] = jnp.minimum(base, y_ref[...])
 
     # ---- halt vote: one VMEM-resident compare per partition ----
     @pl.when(t == n_t - 1)
@@ -149,10 +152,16 @@ def fused_step_pallas(
     vmask: jax.Array,  # (P, NVB, B) float32 0/1
     *,
     sr_name: str = "min_plus",
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Returns ``(x_out (P, NVB, B), changed (P, 1) int32)``."""
     P, T, B, _ = tiles.shape
+    if not interpret and B % 128:
+        # Mosaic slices the HBM tile array in (1, 128) lane tiles: a
+        # narrower tile cannot be DMA'd on its own
+        raise ValueError(
+            f"fused_step_pallas needs a block size that is a multiple of "
+            f"the TPU lane width 128 to compile; got B={B}")
     nvb = x_comb.shape[1]
     nvb_in = x_in.shape[1]
     shared_xin = x_in.shape[0] == 1
@@ -169,7 +178,7 @@ def fused_step_pallas(
         num_scalar_prefetch=2,
         grid=(P, T),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # tiles stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # tiles stay in HBM
             pl.BlockSpec((1, nvb_in, B), xin_map),
             pl.BlockSpec((1, nvb, B), part_row),
             pl.BlockSpec((1, nvb, B), part_row),
@@ -196,7 +205,7 @@ def fused_step_pallas(
         ],
         # the t-walk accumulates into revisited VMEM blocks and the DMA
         # chain crosses the partition boundary: both grid dims sequential
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(jnp.maximum(rows, 0), cols, tiles, x_in, x_comb, x_ref, vmask)

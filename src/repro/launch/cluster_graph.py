@@ -39,6 +39,8 @@ from typing import Dict, List
 
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
+
 APP_PARAMS: Dict[str, dict] = {
     "sssp": {"source": 0},            # sequential pattern
     "pagerank": {"iters": 10},        # independent pattern
@@ -85,6 +87,25 @@ def worker_main(args) -> None:
     np.savez(os.path.join(args.out, f"worker_{rt.process_id}.npz"), **flat)
     rt.barrier("done")
     rt.close()
+
+
+def refuse_on_accelerator(num_processes: int) -> None:
+    """Each localhost worker is a full JAX process: on an accelerator host
+    every one of them would claim all of the host's chips, and all but
+    the first would fail or hang.  The platform is probed in a
+    short-lived child, so this parent holds no device while it spawns."""
+    if num_processes <= 1:
+        return
+    probe = subprocess.run(
+        [sys.executable, "-c", "import jax; print(jax.default_backend())"],
+        capture_output=True, text=True, check=True)
+    platform = probe.stdout.split()[-1]
+    if platform != "cpu":
+        raise SystemExit(
+            f"cluster_graph: JAX platform is {platform!r}; "
+            f"{num_processes} localhost workers would each claim every "
+            f"chip of this host.  Emulate the cluster on CPU "
+            f"(JAX_PLATFORMS=cpu) or run one process per host.")
 
 
 def launch_workers(args, coordinator: str) -> List[subprocess.Popen]:
@@ -178,7 +199,7 @@ def main() -> None:
     ap.add_argument("--deploy", default="/tmp/gofs_cluster")
     ap.add_argument("--out", default="/tmp/gofs_cluster_out")
     ap.add_argument("--transport", default="tcp",
-                    choices=["tcp", "jax", "auto"],
+                    choices=["tcp", "jax"],
                     help="tcp: host-lane exchange only (CI default); "
                          "jax: also initialize jax.distributed")
     ap.add_argument("--cache-slots", type=int, default=14)
@@ -187,6 +208,7 @@ def main() -> None:
                     help="run the single-process reference and assert "
                          "bitwise parity + per-host staged-byte savings")
     args = ap.parse_args()
+    use_compile_cache()
     for app in args.apps.split(","):
         assert app in APP_PARAMS, f"unknown app {app!r}"
 
@@ -196,6 +218,7 @@ def main() -> None:
 
     from repro.launch.run_graph import ensure_deployment
 
+    refuse_on_accelerator(args.num_processes)
     ensure_deployment(args.size, args.deploy, args.cache_slots)  # once
     coordinator = f"127.0.0.1:{free_port()}"
     t0 = time.time()

@@ -26,6 +26,7 @@ from repro.configs import get_graph_config
 from repro.core.algorithms import nhop, pagerank, sssp, tracking
 from repro.core.generator import generate_collection
 from repro.gofs import GoFSStore, deploy_collection
+from repro.launch.compile_cache import use_compile_cache
 
 
 def ensure_deployment(size: str, root: str, cache_slots: int):
@@ -103,6 +104,7 @@ def main() -> None:
                     help="print the execution plan (auto-selected knobs + "
                          "cost estimates) and exit without executing")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg, store = ensure_deployment(args.size, args.deploy, args.cache_slots)
 

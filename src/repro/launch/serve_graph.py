@@ -20,6 +20,7 @@ import time
 import numpy as np
 
 from repro.gopher import GopherService
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.run_graph import ensure_deployment
 
 
@@ -52,6 +53,7 @@ def main(argv=None) -> None:
                    help="skip warming the caches before timing")
     p.add_argument("--seed", type=int, default=7)
     args = p.parse_args(argv)
+    use_compile_cache()
 
     cfg, store = ensure_deployment(args.size, args.deploy, args.cache_slots)
     rng = np.random.default_rng(args.seed)

@@ -27,6 +27,7 @@ from repro.core.generator import generate_collection
 from repro.core.graph import TimeSeriesGraph
 from repro.gofs import GoFSStore, append_instances, deploy_collection
 from repro.gopher import GopherService, GopherSession
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.run_graph import get_graph_config
 
 
@@ -49,6 +50,7 @@ def main(argv=None) -> None:
     p.add_argument("--fresh", action="store_true",
                    help="wipe an existing deployment at --deploy")
     args = p.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_graph_config(args.size)
     tsg = generate_collection(cfg)

@@ -13,7 +13,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.models.layers import ParamDef, apply_rope
 
 Params = Any
@@ -229,7 +228,7 @@ def flash_decode_tp(
         return out.astype(q_l.dtype), k_l, v_l, pos_l
 
     kv_spec = P(bspec, tp, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=runtime.mesh,
         in_specs=(

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.layers import ParamDef, _act, _gated
 
 Params = Any
@@ -195,7 +194,7 @@ def moe_apply_ep(
     if cfg.moe.shared_expert:
         shared_args = (p["shared_wi"], p["shared_wo"])
         shared_specs = (P(None, None), P(None, None))
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(x_spec, P(None, None), P(tp_axis, None, None), P(tp_axis, None, None))

@@ -290,3 +290,31 @@ def test_two_process_parity_end_to_end(tmp_path):
         capture_output=True, text=True, timeout=TIMEOUT)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "parity OK" in proc.stdout
+
+
+def test_unknown_transport_raises():
+    """A transport is chosen, not guessed: anything but tcp/jax raises
+    instead of silently falling back."""
+    from repro.cluster.runtime import init_cluster
+
+    with pytest.raises(ValueError, match="transport"):
+        init_cluster("127.0.0.1:1", 2, 0, transport="auto")
+
+
+def test_localhost_workers_refused_on_an_accelerator(monkeypatch):
+    """Several localhost workers would each claim every chip of an
+    accelerator host: the launcher probes the platform in a child and
+    refuses; one process, or a CPU platform, passes."""
+    from repro.launch import cluster_graph
+
+    def probe(platform):
+        def run(*_a, **_kw):
+            return subprocess.CompletedProcess([], 0, stdout=f"{platform}\n")
+        return run
+
+    monkeypatch.setattr(cluster_graph.subprocess, "run", probe("tpu"))
+    with pytest.raises(SystemExit, match="claim every chip"):
+        cluster_graph.refuse_on_accelerator(2)
+    cluster_graph.refuse_on_accelerator(1)
+    monkeypatch.setattr(cluster_graph.subprocess, "run", probe("cpu"))
+    cluster_graph.refuse_on_accelerator(2)

@@ -24,7 +24,11 @@ from repro.models.moe import moe_apply_ep, moe_apply_local, moe_schema
 from repro.models.layers import init_params
 from repro.train.data import SyntheticLMDataset
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+# the LM stack relies on GSPMD propagation: Auto axes (jax.make_mesh's
+# default became Explicit, under which the vocab-parallel gather has no
+# unambiguous out sharding)
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rt = Runtime(mesh=mesh, dp_axes=("data",), tp_axis="model")
 
 # ---- full train forward: dense (vocab-parallel loss + embed + SP) --------

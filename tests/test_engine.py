@@ -162,6 +162,8 @@ for pattern in ("sequential", "independent"):
         f = np.isfinite(rs.values[t])
         assert np.array_equal(np.isfinite(rm.values[t]), f), (pattern, t)
         assert np.allclose(rm.values[t][f], rs.values[t][f]), (pattern, t)
+    # final is the last instance's state, also when instances are sharded
+    assert np.array_equal(rm.final, rm.values[-1]), pattern
 pw = pagerank.edge_weights_for_instances(tmpl.src, active, tmpl.num_vertices)
 pp = pagerank_program(tmpl.num_vertices, iters=10)
 rm = eng_m.run(pp, pw, pattern="eventually", merge="mean")

@@ -199,3 +199,16 @@ def test_decode_attention_vs_ref(case):
         np.asarray(o_pal, np.float32), np.asarray(o_ref, np.float32),
         rtol=tol, atol=tol,
     )
+
+
+def test_fused_kernel_refuses_sub_lane_blocks():
+    """Compiled (not interpreted), the fused kernel needs lane-width
+    blocks: a narrower B raises a clear error instead of a Mosaic one."""
+    from repro.kernels.semiring_superstep.kernel import fused_step_pallas
+
+    P_, T_, nvb, B = 2, 3, 2, 64
+    tiles = jnp.zeros((P_, T_, B, B), jnp.float32)
+    idx = jnp.zeros((P_, T_), jnp.int32)
+    x = jnp.zeros((P_, nvb, B), jnp.float32)
+    with pytest.raises(ValueError, match="lane width"):
+        fused_step_pallas(tiles, idx, idx, x, x, x, x, interpret=False)

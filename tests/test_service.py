@@ -217,3 +217,29 @@ def test_engine_failure_delivered_and_loop_survives(service):
     # the serve loop must still be alive and serving
     out = service.query("sssp", source=0, timeout=120)
     assert np.isfinite(out.output["final"][0])
+
+
+def test_over_budget_batches_stream_from_the_store(tiny_gofs):
+    """A store batch larger than the warm cache's budget could never stay
+    resident: every query streams it from the store chunk by chunk (never
+    materializing it whole) and the answers equal plain session runs."""
+    from repro.gofs import GoFSStore
+
+    from tests.conftest import TINY
+
+    ref = GopherSession(GoFSStore(tiny_gofs), block_size=TINY.block_size)
+    reqs = [("sssp", {"source": 0}), ("sssp", {"source": 5}),
+            ("nhop", {"source": 3, "n_hops": 2})]
+    store = GoFSStore(tiny_gofs)
+
+    def no_materialize(*_a, **_kw):
+        raise AssertionError("over-budget batch was materialized whole")
+
+    store.load_blocked = no_materialize
+    with GopherService(store, block_size=TINY.block_size,
+                       staging_cache_bytes=1) as svc:
+        got = svc.query_many(reqs)
+        stats = svc.session.staging_cache_stats()
+    assert stats["resident_bytes"] == 0 and stats["staged_bytes"] > 0
+    for (name, params), g in zip(reqs, got):
+        _assert_same_output(ref.run(ref.plan(name, **params)), g, name)

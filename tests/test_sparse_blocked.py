@@ -370,7 +370,8 @@ def test_kernel_mode_resolution():
 
 def test_planner_kernel_auto_selection(env):
     """Planner kernel knob: off on non-TPU backends, fused for TPU +
-    sparse-regime occupancy, spmv for TPU dense; overrides win."""
+    sparse-regime occupancy at lane-width blocks, spmv for TPU dense or
+    narrower blocks; overrides win."""
     from repro.gopher import GopherSession, get_analytic
     from repro.gopher.planner import plan_analytic
 
@@ -395,6 +396,10 @@ def test_planner_kernel_auto_selection(env):
                   store_backed=False, num_instances=2)
     low = plan_analytic(a, {"source": 0}, occupancy=0.1,
                         sparse_buckets=None, backend="tpu", **common)
+    assert low.kernel.value == "spmv"  # B=32: the fused DMA cannot compile
+    lane = dict(common, bg=build_blocked(tmpl, bg.part_of, 128))
+    low = plan_analytic(a, {"source": 0}, occupancy=0.1,
+                        sparse_buckets=None, backend="tpu", **lane)
     assert low.kernel.value == "fused"
     high = plan_analytic(a, {"source": 0}, occupancy=0.9,
                          sparse_buckets=None, backend="tpu", **common)
