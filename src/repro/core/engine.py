@@ -71,6 +71,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.blocked import BlockedGraph, SparseBlocked
 from repro.core.comm import CommBackend, make_comm
@@ -523,6 +524,9 @@ class TemporalEngine:
             jnp.asarray(shard(bg.btiles_rc[:, :, 1])),
         ) + self._struct_tail
         self._runners: Dict[Any, Callable] = {}
+        # runners _runner made that have not been called yet: their first
+        # call builds the program (see _call)
+        self._unbuilt: set = set()
         self._merge_fns: Dict[int, Callable] = {}
         # staged-batch device cache: host-array identity (weakly held) ->
         # device arrays (see _cached_device) so repeated runs over one
@@ -781,6 +785,7 @@ class TemporalEngine:
                     program, pattern, merge, n_instances, sparse, warm=warm,
                     multi=multi,
                 )
+            self._unbuilt.add(self._runners[key])
         return self._runners[key]
 
     # ------------------------------------------------- cluster shard slicing
@@ -814,11 +819,22 @@ class TemporalEngine:
         )
 
     # ------------------------------------------------------------ dispatch
+    def _call(self, run_fn, args):
+        """Call a runner.  The first call of a runner ``_runner`` made is
+        its build (trace, lower, compile or load from the persistent
+        cache, then enqueue), spanned as ``engine.build``; later calls
+        hit jit's cache and open no span."""
+        if run_fn not in self._unbuilt:
+            return run_fn(*args)
+        self._unbuilt.discard(run_fn)
+        with TraceAnnotation("engine.build"):
+            return run_fn(*args)
+
     def _dispatch(self, run_fn, *args):
         if self.mesh is not None:
             with self.mesh:
-                return run_fn(*args)
-        out = run_fn(*args)
+                return self._call(run_fn, args)
+        out = self._call(run_fn, args)
         if self.parts is not None:
             # cluster mode: the runner's pure_callback exchanges ride the
             # SEQUENCED inter-process channel, and so do the host-side
@@ -852,7 +868,8 @@ class TemporalEngine:
                                    zip(hit[0], host_arrays)):
             self._staged_device.move_to_end(key)
             return hit[1]
-        dev = tuple(_device_put(a) for a in host_arrays)
+        with TraceAnnotation("engine.put"):
+            dev = tuple(_device_put(a) for a in host_arrays)
         self._staged_device[key] = (
             tuple(weakref.ref(a) for a in host_arrays), dev,
         )
@@ -923,14 +940,14 @@ class TemporalEngine:
                 sparse_seen = True
                 nnz_total += (int(self._shard_axis(ch.nnz).sum())
                               + int(self._shard_axis(ch.bnnz).sum()))
-                bufs = tuple(_device_put(self._shard_axis(a)) for a in (
-                    ch.tiles, ch.btiles, ch.rows, ch.cols, ch.brows, ch.bcols
-                ))
+                host = (ch.tiles, ch.btiles, ch.rows, ch.cols, ch.brows,
+                        ch.bcols)
                 tail = self._struct_tail
             else:
-                bufs = (_device_put(self._shard_axis(ch.tiles)),
-                        _device_put(self._shard_axis(ch.btiles)))
+                host = (ch.tiles, ch.btiles)
                 tail = self._struct
+            with TraceAnnotation("engine.put"):
+                bufs = tuple(_device_put(self._shard_axis(a)) for a in host)
             for k, s in enumerate(specs):
                 warm_k = s.effective_warm()
                 # warm chunks chain exactly like sequential: the carry is
@@ -1173,7 +1190,16 @@ class TemporalEngine:
     def _wrap_result(self, pattern: str, merge: Optional[str], out,
                      occ: Optional[float], warm: bool = False,
                      n_sources: Optional[int] = None) -> EngineResult:
-        """Gather device outputs back to global vertex order + stats."""
+        """Gather device outputs back to global vertex order + stats,
+        spanned as ``engine.gather`` (on a chip this also waits for the
+        run to finish)."""
+        with TraceAnnotation("engine.gather"):
+            return self._gather_result(pattern, merge, out, occ, warm,
+                                       n_sources)
+
+    def _gather_result(self, pattern: str, merge: Optional[str], out,
+                       occ: Optional[float], warm: bool,
+                       n_sources: Optional[int]) -> EngineResult:
         xs, _, merged, ss, lsw = out
         bg = self.bg
         if self.parts is not None:

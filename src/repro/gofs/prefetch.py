@@ -63,6 +63,7 @@ from dataclasses import dataclass
 from typing import Callable, Iterator, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 THREAD_PREFIX = "gofs-prefetch"
 
@@ -230,8 +231,12 @@ class SlicePrefetcher:
     def _stage(self, span: Tuple[int, int]) -> StagedChunk:
         """Read + fill one chunk into chunk-owned buffers (runs on the
         pool, so both the reads AND the fill/allocation overlap the
-        consumer's execution)."""
-        s, e = span
+        consumer's execution; on the caller when synchronous).  Spanned
+        as ``gofs.stage`` on the profiler's trace."""
+        with TraceAnnotation("gofs.stage"):
+            return self._fill(*span)
+
+    def _fill(self, s: int, e: int) -> StagedChunk:
         n = e - s
         if self.stage_fn is not None:
             return self.stage_fn(s, e)
@@ -308,7 +313,9 @@ class SlicePrefetcher:
                 except IndexError:  # drained, or cleared by close()
                     return
                 try:
-                    chunk = fut.result()
+                    # the consumer blocked on the next chunk
+                    with TraceAnnotation("gofs.wait"):
+                        chunk = fut.result()
                 except CancelledError:
                     # a concurrent close() — e.g. a session observing an
                     # append mid-stream — cancelled this chunk between our

@@ -84,6 +84,7 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.gopher.registry import get_analytic
 from repro.gopher.session import (AnalyticResult, GopherSession, TailUpdate,
@@ -236,7 +237,6 @@ class GopherService:
         self._served = 0
         self._batches = 0
         self._widest_batch = 0
-        self._t_started: Optional[float] = None
 
     # ------------------------------------------------------------ lifecycle
     def start(self) -> "GopherService":
@@ -245,7 +245,6 @@ class GopherService:
         called."""
         if self._thread is None or not self._thread.is_alive():
             self._stopping = False
-            self._t_started = time.perf_counter()
             self._thread = threading.Thread(
                 target=self._serve_loop, name="gopher-serve", daemon=True)
             self._thread.start()
@@ -446,6 +445,12 @@ class GopherService:
         return (t.analytic, rest, tuple(sorted(t.plan_kw.items())))
 
     def _execute(self, batch: List[QueryTicket]) -> None:
+        """Run one admitted batch, spanned as ``service.execute`` on the
+        serve thread (``queries``: the batch's width)."""
+        with TraceAnnotation("service.execute", queries=len(batch)):
+            self._run_batch(batch)
+
+    def _run_batch(self, batch: List[QueryTicket]) -> None:
         """Group the admitted tickets, run them as one ``run_many`` pass
         (shared staging across groups), split the query axis, deliver."""
         # ---- coalesce: same analytic + same non-source params -> one plan
@@ -507,8 +512,6 @@ class GopherService:
         """Serving stats: latency percentiles over the last requests,
         batch shape, and the warm cache's staging economy."""
         lats = np.asarray(self._latencies, np.float64)
-        elapsed = (time.perf_counter() - self._t_started) \
-            if self._t_started is not None else 0.0
         return {
             "served": self._served,
             "batches": self._batches,
@@ -517,8 +520,6 @@ class GopherService:
             else None,
             "p95_ms": float(np.percentile(lats, 95) * 1e3) if lats.size
             else None,
-            "throughput_qps": self._served / elapsed if elapsed > 0
-            else 0.0,
             "staging_cache": self.session.staging_cache_stats(),
             "subscriptions": len(self._subs),
             "appends_observed": self._appends_observed,
