@@ -63,6 +63,7 @@ the host engine so the two paths are directly comparable.
 """
 from __future__ import annotations
 
+import dataclasses
 import weakref
 from collections import OrderedDict
 from dataclasses import dataclass
@@ -124,6 +125,24 @@ class SemiringProgram:
     'min_plus'
     >>> pagerank_program(100, iters=5).iters   # non-idempotent -> iterate
     5
+
+    **What the engine's runner cache keys on** (:attr:`runner_key`):
+    every field the traced runner reads — ``name``, ``semiring``,
+    ``zero_fill``, ``kind``, the fixpoint knobs, ``iters`` and ``step`` —
+    and never ``init``.  ``init`` runs on the host before dispatch and
+    only computes ``x0``, which reaches the runner as data, so programs
+    that differ only in their seed (SSSP from another source, another
+    batch of sources) share one built runner.  ``step`` is traced, so it
+    must compare by value: two steps that trace the same computation
+    should be equal (see ``_PageRankStep``); a fresh closure per program
+    is correct but builds a runner of its own.
+
+    >>> a = min_plus_program("sssp", init=source_init(0))
+    >>> b = min_plus_program("sssp", init=source_init(7))
+    >>> a == b, a.runner_key == b.runner_key
+    (False, True)
+    >>> pagerank_program(100).runner_key == pagerank_program(100).runner_key
+    True
     """
 
     name: str
@@ -148,6 +167,13 @@ class SemiringProgram:
                 "fixpoint programs need an idempotent semiring"
         else:
             assert self.step is not None and self.iters > 0
+
+    @property
+    def runner_key(self) -> Tuple:
+        """The fields the engine's traced runner reads: its cache key."""
+        return (self.name, self.semiring, self.zero_fill, self.kind,
+                self.subgraph_centric, self.max_supersteps,
+                self.max_local_sweeps, self.iters, self.step)
 
 
 def source_init(source_vertex: int, pad: float = INF):
@@ -216,16 +242,27 @@ def min_plus_program(
     )
 
 
+@dataclass(frozen=True)
+class _PageRankStep:
+    """PageRank's superstep as a value: programs with equal parameters
+    compare equal, so they share one built runner."""
+
+    num_vertices: int
+    damping: float
+
+    def __call__(self, x, dg, comm, use_pallas):
+        return pagerank_step(
+            x, dg, comm, damping=self.damping,
+            num_vertices=self.num_vertices, use_pallas=use_pallas,
+        )
+
+
 def pagerank_program(
     num_vertices: int, *, damping: float = 0.85, iters: int = 30
 ) -> SemiringProgram:
     """Fixed-iteration plus-mul PageRank (independent pattern workload)."""
-
-    def step(x, dg, comm, use_pallas):
-        return pagerank_step(
-            x, dg, comm, damping=damping, num_vertices=num_vertices,
-            use_pallas=use_pallas,
-        )
+    num_vertices = int(num_vertices)
+    step = _PageRankStep(num_vertices, float(damping))
 
     def init(bg: BlockedGraph) -> np.ndarray:
         valid = (bg.global_of >= 0)
@@ -403,8 +440,15 @@ class TemporalEngine:
       a multiple of the data-axis size or the per-chunk runners fall back
       to replicated instances.
 
-    Jitted runners are cached per (program, pattern, instance count), so
-    repeated calls (e.g. tracking's per-timestep probes) recompile nothing.
+    Jitted runners are cached per (program structure, pattern, merge,
+    instance count, layout, warm start, query axis): the program's part of
+    the key is :attr:`SemiringProgram.runner_key`, which leaves out the
+    host-only ``init``.  So a program for a new source, a new batch of
+    sources or a new pass reuses the built runner, and repeated calls
+    (tracking's per-timestep probes, a service's batches) rebuild nothing.
+    A runner builds once per argument signature (shapes and dtypes): a new
+    query-axis width on a warm runner is a build too.  ``runner_builds``
+    counts those builds and ``runner_hits`` the calls that built nothing.
 
     Example — one tiny graph, all three patterns, sync and async staging:
 
@@ -524,9 +568,11 @@ class TemporalEngine:
             jnp.asarray(shard(bg.btiles_rc[:, :, 1])),
         ) + self._struct_tail
         self._runners: Dict[Any, Callable] = {}
-        # runners _runner made that have not been called yet: their first
-        # call builds the program (see _call)
-        self._unbuilt: set = set()
+        # (runner, argument signature) pairs already called: the first
+        # call of each pair builds the program (see _call)
+        self._built: set = set()
+        self.runner_builds = 0
+        self.runner_hits = 0
         self._merge_fns: Dict[int, Callable] = {}
         # staged-batch device cache: host-array identity (weakly held) ->
         # device arrays (see _cached_device) so repeated runs over one
@@ -774,8 +820,12 @@ class TemporalEngine:
                 merge: Optional[str], n_instances: int,
                 sparse: bool = False, warm: bool = False,
                 multi: bool = False):
-        key = (program, pattern, merge, n_instances, sparse, warm, multi)
+        key = (program.runner_key, pattern, merge, n_instances, sparse,
+               warm, multi)
         if key not in self._runners:
+            # the runner keeps the traced fields only: a per-call ``init``
+            # (and the sources it closes over) is not held for its lifetime
+            program = dataclasses.replace(program, init=None)
             if self.mesh is None:
                 self._runners[key] = self._make_stacked_runner(
                     program, pattern, merge, sparse, warm=warm, multi=multi
@@ -785,7 +835,6 @@ class TemporalEngine:
                     program, pattern, merge, n_instances, sparse, warm=warm,
                     multi=multi,
                 )
-            self._unbuilt.add(self._runners[key])
         return self._runners[key]
 
     # ------------------------------------------------- cluster shard slicing
@@ -805,8 +854,6 @@ class TemporalEngine:
 
     def _shard_sparse_batch(self, sp: SparseBlocked) -> SparseBlocked:
         """Slice a full-width pre-staged packed batch to the shard."""
-        import dataclasses
-
         lo, hi = self.parts
         if sp.tiles.shape[1] == hi - lo:
             return sp
@@ -820,13 +867,17 @@ class TemporalEngine:
 
     # ------------------------------------------------------------ dispatch
     def _call(self, run_fn, args):
-        """Call a runner.  The first call of a runner ``_runner`` made is
-        its build (trace, lower, compile or load from the persistent
-        cache, then enqueue), spanned as ``engine.build``; later calls
-        hit jit's cache and open no span."""
-        if run_fn not in self._unbuilt:
+        """Call a runner.  The first call of a runner with an argument
+        signature (shapes and dtypes) is a build (trace, lower, compile
+        or load from the persistent cache, then enqueue), spanned as
+        ``engine.build`` and counted in ``runner_builds``; later calls
+        hit jit's cache, open no span and count in ``runner_hits``."""
+        key = (run_fn, tuple((a.shape, a.dtype) for a in args))
+        if key in self._built:
+            self.runner_hits += 1
             return run_fn(*args)
-        self._unbuilt.discard(run_fn)
+        self._built.add(key)
+        self.runner_builds += 1
         with TraceAnnotation("engine.build"):
             return run_fn(*args)
 

@@ -510,7 +510,8 @@ class GopherService:
     # ------------------------------------------------------------ reporting
     def report(self) -> Dict[str, Any]:
         """Serving stats: latency percentiles over the last requests,
-        batch shape, and the warm cache's staging economy."""
+        batch shape, the warm cache's staging economy, and the engine's
+        program builds and runner-cache hits since the session began."""
         lats = np.asarray(self._latencies, np.float64)
         return {
             "served": self._served,
@@ -523,6 +524,7 @@ class GopherService:
             "staging_cache": self.session.staging_cache_stats(),
             "subscriptions": len(self._subs),
             "appends_observed": self._appends_observed,
+            **self.session.runner_stats(),
         }
 
 

@@ -53,6 +53,8 @@ array([0., 1., 1., 2.], dtype=float32)
 array([inf,  0.,  1.,  2.], dtype=float32)
 >>> sess.last_run_report["staging_passes"]  # two analytics, one staging
 1
+>>> sess.last_run_report["runner_builds"]   # new sources, same program
+0
 """
 from __future__ import annotations
 
@@ -171,15 +173,12 @@ class TailUpdate:
 class _TailState:
     """Held state of one tailing computation: how far the instance axis
     has been consumed and the last combined result (whose engine
-    ``final`` seeds the next incremental step).  ``program`` is the
-    compiled semiring program reused across steps — a tail key pins the
-    analytic's params, and programs are append-invariant, so reusing the
-    object keeps the engine's traced runner cache hot (a fresh program
-    per step would re-trace every append)."""
+    ``final`` seeds the next incremental step).  Each step makes its
+    program afresh: the engine keys its runners on the program's
+    structure, so a step retraces nothing a previous step built."""
 
     processed: int
     result: "AnalyticResult"
-    program: Any = None
 
 
 @dataclass
@@ -525,6 +524,7 @@ class GopherSession:
         cache = self._staging_cache if self._staging_cache is not None \
             else _StagingCache()
         base = (cache.staged_bytes, cache.staging_passes, cache.hits)
+        runners = self.runner_stats()
         results: List[Optional[AnalyticResult]] = [None] * len(plans)
         resolved = [get_analytic(p.analytic) for p in plans]
 
@@ -614,8 +614,17 @@ class GopherSession:
             "cache_hits": cache.hits - base[2],
             "resident_bytes": cache.resident_bytes,
             "analytics": [p.analytic for p in plans],
+            **{k: v - runners[k] for k, v in self.runner_stats().items()},
         }
         return results  # type: ignore[return-value]
+
+    def runner_stats(self) -> Dict[str, int]:
+        """Cumulative runner counters over the session's engines:
+        ``runner_builds`` (calls that traced and built a program) and
+        ``runner_hits`` (calls that reused a built one)."""
+        engines = tuple(self._engines.values())  # read from other threads
+        return {"runner_builds": sum(e.runner_builds for e in engines),
+                "runner_hits": sum(e.runner_hits for e in engines)}
 
     def staging_cache_stats(self) -> Optional[Dict[str, Any]]:
         """Cumulative counters of the session-lifetime staging cache
@@ -772,9 +781,7 @@ class GopherSession:
         cache = self._staging_cache if self._staging_cache is not None \
             else _StagingCache()
         ctx = PlanContext(self, plan, a, cache)
-        program = st.program
-        if program is None:
-            program = a.make_program(ctx, **plan.param_dict)
+        program = a.make_program(ctx, **plan.param_dict)
         engine = self._engine(plan.graph, plan.comm.value,
                               plan.kernel.value)
         warm = bool(plan.warm.value) and program.kind == "fixpoint"
@@ -807,8 +814,7 @@ class GopherSession:
             _num_vertices=res_new._num_vertices,
         )
         result = self._wrap(plan, a, combined, cache)
-        self._tails[key] = _TailState(processed=n, result=result,
-                                      program=program)
+        self._tails[key] = _TailState(processed=n, result=result)
         return TailUpdate(result, n_new, "incremental", version)
 
     # ------------------------------------------------------------ internals
