@@ -127,7 +127,7 @@ def window(store: GoFSStore, n: int) -> GoFSStore:
 def lowered_kernel_calls(sess: GopherSession, plan) -> int:
     """``tpu_custom_call`` sites in every dense single-source step the
     plan's engine compiled."""
-    eng = sess._engine(plan.graph, plan.comm.value, plan.kernel.value)
+    eng = sess._engine(plan)
     bg, B = eng.bg, eng.bg.block_size
     f32 = jnp.float32
     calls = 0

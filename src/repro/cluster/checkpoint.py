@@ -185,8 +185,7 @@ class ResumableRun:
             else _StagingCache()
         ctx = PlanContext(sess, plan, a, cache)
         program = a.make_program(ctx, **plan.param_dict)
-        engine = sess._engine(plan.graph, plan.comm.value,
-                              plan.kernel.value)
+        engine = sess._engine(plan)
         warm = bool(plan.warm.value) and program.kind == "fixpoint"
         zero = float(a.zero_fill)
 

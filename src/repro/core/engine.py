@@ -26,10 +26,13 @@ pattern                   execution
 ========================  =================================================
 
 Placement: ``mesh=None`` runs stacked on one device (tests, benches);
-with a mesh the engine lowers to ``shard_map`` — partitions one-per-device
-over ``model_axes``, and for the temporally concurrent patterns instances
-over ``data_axis``.  The boundary exchange is ONE combine per superstep
-either way, routed through a pluggable comm backend
+with a mesh the engine lowers to ``shard_map`` — partitions in contiguous
+blocks of ``P / m`` per device over the ``m`` devices of ``model_axes``,
+and for the temporally concurrent patterns instances over ``data_axis``.
+Staged batches are uploaded straight to their shards (a
+``NamedSharding``), never whole to one device.  The boundary exchange is
+ONE combine per superstep either way, routed through a pluggable comm
+backend
 (``comm="dense" | "ring" | "host"`` — see ``repro.core.comm``): the dense
 psum/pmin all-reduce (default), a collective-permute ring for multi-pod
 DCI topologies, or a mesh-free host-side gather for CPU clusters.
@@ -73,6 +76,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core.blocked import BlockedGraph, SparseBlocked
 from repro.core.comm import CommBackend, make_comm
@@ -85,6 +89,7 @@ from repro.core.superstep import (
     kernel_mode,
     pagerank_step,
 )
+from repro.dist.collectives import boundary_exchange_bytes
 
 PATTERNS = ("sequential", "independent", "eventually")
 
@@ -93,11 +98,12 @@ PATTERNS = ("sequential", "independent", "eventually")
 _STAGED_CACHE_SLOTS = 4
 
 
-def _device_put(x) -> jax.Array:
-    """Host buffer -> device array.  All staged-value uploads route through
-    this seam so tests (and the re-upload regression gate) can count them;
-    a no-op for arrays already on device."""
-    return jnp.asarray(x)
+def _device_put(x, sharding: Optional[NamedSharding] = None) -> jax.Array:
+    """Host buffer -> device array (the default device, or the shards of
+    ``sharding``).  All staged-value uploads route through this seam so
+    tests (and the re-upload regression gate) can count them; a no-op for
+    arrays already on device."""
+    return jnp.asarray(x) if sharding is None else jax.device_put(x, sharding)
 
 
 # ---------------------------------------------------------------------------
@@ -384,11 +390,16 @@ class TemporalEngine:
 
     * ``mesh=None`` — stacked: all partitions stacked on one device's
       leading axis, instances scanned (CPU tests and benchmarks).
-    * ``mesh=...`` — SPMD ``shard_map``: partitions sharded one-per-device
-      over ``model_axes``; for ``independent``/``eventually`` the instance
-      axis additionally shards over ``data_axis`` (temporal parallelism)
-      whenever the instance count divides the data-axis size, else
-      instances are replicated (still correct, no speedup).
+    * ``mesh=...`` — SPMD ``shard_map``: partitions sharded in contiguous
+      blocks of ``P / m`` over the ``m`` devices of ``model_axes``; for
+      cold ``independent``/``eventually`` runs the instance axis
+      additionally shards over ``data_axis`` (temporal parallelism)
+      whenever the data-axis size divides the instance count, else
+      instances are replicated (still correct, no speedup).  Staged
+      batches upload straight to those shards, the vertex state too, and
+      the sequential carry stays sharded from one streamed chunk to the
+      next.  ``exchange_bytes`` counts the boundary bytes one device
+      sends (``exchange_bytes_per_superstep`` per superstep).
 
     **Comm backend** (how the boundary exchange moves bytes; see
     ``repro.core.comm`` and the selection table in
@@ -573,6 +584,7 @@ class TemporalEngine:
         self._built: set = set()
         self.runner_builds = 0
         self.runner_hits = 0
+        self.exchange_bytes = 0  # cumulative, over this engine's runs
         self._merge_fns: Dict[int, Callable] = {}
         # staged-batch device cache: host-array identity (weakly held) ->
         # device arrays (see _cached_device) so repeated runs over one
@@ -589,12 +601,60 @@ class TemporalEngine:
         w = np.asarray(instance_weights, np.float32)
         if w.ndim == 1:
             w = w[None]
-        return (
-            jnp.asarray(self.bg.fill_local_batch(w, zero=zero_fill,
-                                                 parts=self.parts)),
-            jnp.asarray(self.bg.fill_boundary_batch(w, zero=zero_fill,
-                                                    parts=self.parts)),
+        filled = (
+            self.bg.fill_local_batch(w, zero=zero_fill, parts=self.parts),
+            self.bg.fill_boundary_batch(w, zero=zero_fill, parts=self.parts),
         )
+        sh = self._staged_sharding()
+        return tuple(jnp.asarray(a) if sh is None else _device_put(a, sh)
+                     for a in filled)
+
+    # ---------------------------------------------------------- placement
+    @property
+    def _maxes(self):
+        """The model axes as a ``PartitionSpec`` entry."""
+        return self.model_axes if len(self.model_axes) > 1 \
+            else self.model_axes[0]
+
+    def _instance_axis(self, pattern: str, warm: bool, n_instances: int):
+        """The mesh axis a runner shards the instance axis over:
+        ``data_axis`` for cold temporally concurrent patterns whose
+        instance count the data axis divides, else ``None`` (replicated:
+        single-instance probes, ragged collections, and warm chains,
+        whose instance t needs t-1's converged state)."""
+        d = self._data_size()
+        shard = (pattern in ("independent", "eventually") and not warm
+                 and n_instances % d == 0 and n_instances >= d)
+        return self.data_axis if shard else None
+
+    def _staged_sharding(self, iaxis=None) -> Optional[NamedSharding]:
+        """Where a staged (I, P, ...) batch lives: ``None`` (the default
+        device) stacked; on a mesh, partitions over the model axes and
+        instances over ``iaxis``."""
+        if self.mesh is None:
+            return None
+        return NamedSharding(self.mesh, PartitionSpec(iaxis, self._maxes))
+
+    def _put(self, host_arrays, iaxis=None) -> Tuple[jax.Array, ...]:
+        """Upload one group of staged buffers (one ``engine.put`` span)."""
+        sh = self._staged_sharding(iaxis)
+        with TraceAnnotation("engine.put"):
+            if sh is None:  # the one-argument seam the upload tests count
+                return tuple(_device_put(a) for a in host_arrays)
+            return tuple(_device_put(a, sh) for a in host_arrays)
+
+    @property
+    def exchange_bytes_per_superstep(self) -> float:
+        """Boundary bytes one device sends in one superstep's exchange
+        (``boundary_exchange_bytes`` over the published vertices and the
+        model axes' devices, under this engine's comm backend); 0 stacked,
+        where the fold never leaves the device."""
+        if self.mesh is None:
+            return 0.0
+        m = int(np.prod([self.mesh.shape[a] for a in self.model_axes]))
+        return float(boundary_exchange_bytes(
+            self.bg.num_boundary, m, self.comm.name,
+            boundary_nnz=int(self.bg.boundary_nnz))["bytes_per_device"])
 
     def stage_sparse(
         self, instance_weights: np.ndarray, zero_fill: float
@@ -717,25 +777,19 @@ class TemporalEngine:
                           merge: Optional[str], n_instances: int,
                           sparse: bool = False, warm: bool = False,
                           multi: bool = False):
-        from jax.sharding import PartitionSpec as P_
-
+        P_ = PartitionSpec
         mesh = self.mesh
-        maxes = self.model_axes if len(self.model_axes) > 1 \
-            else self.model_axes[0]
+        maxes = self._maxes
         daxis = self.data_axis
         # temporal concurrency: shard the instance axis over data only when
         # it divides — single-instance probes (tracking, nhop hops) and
         # ragged collections fall back to replicated instances, which stays
         # correct (every data group computes the same states; the Merge
-        # psum normalizes by the psum'd instance count).
-        temporal = pattern in ("independent", "eventually")
-        # warm-started fixpoints chain state from instance t-1 to t, so the
-        # instance axis cannot be data-sharded (a shard's first instance
-        # would lose its predecessor); replicated instances keep the chain
-        # intact on every data group and stay bitwise-correct.
-        shard_instances = (temporal and not warm
-                           and n_instances % self._data_size() == 0
-                           and n_instances >= self._data_size())
+        # psum normalizes by the psum'd instance count).  Warm-started
+        # fixpoints chain state from instance t-1 to t, so they replicate
+        # too: every data group keeps the chain intact, bitwise-correct.
+        iaxis = self._instance_axis(pattern, warm, n_instances)
+        shard_instances = iaxis is not None
         # data-sharded instances run data-dependent superstep loops
         # concurrently; backends with globally scheduled collectives (the
         # ppermute ring) must equalize trip counts over the data axis or
@@ -769,8 +823,6 @@ class TemporalEngine:
                 idx=(rows, cols, brows, bcols), warm=warm,
             )
             return xs, final, merged_of(xs, final), ss, lsw
-
-        iaxis = daxis if shard_instances else None
 
         local = local_sparse if sparse else local_dense
         if multi:
@@ -919,8 +971,7 @@ class TemporalEngine:
                                    zip(hit[0], host_arrays)):
             self._staged_device.move_to_end(key)
             return hit[1]
-        with TraceAnnotation("engine.put"):
-            dev = tuple(_device_put(a) for a in host_arrays)
+        dev = self._put(host_arrays)
         self._staged_device[key] = (
             tuple(weakref.ref(a) for a in host_arrays), dev,
         )
@@ -997,8 +1048,15 @@ class TemporalEngine:
             else:
                 host = (ch.tiles, ch.btiles)
                 tail = self._struct
-            with TraceAnnotation("engine.put"):
-                bufs = tuple(_device_put(self._shard_axis(a)) for a in host)
+            # on a mesh the chunk goes straight to its shards: partitions
+            # over the model axes, instances over data when every spec's
+            # runner shards them there
+            iaxis = None
+            if self.mesh is not None:
+                axes = {self._instance_axis(s.pattern, s.effective_warm(), n)
+                        for s in specs}
+                iaxis = axes.pop() if len(axes) == 1 else None
+            bufs = self._put([self._shard_axis(a) for a in host], iaxis)
             for k, s in enumerate(specs):
                 warm_k = s.effective_warm()
                 # warm chunks chain exactly like sequential: the carry is
@@ -1160,6 +1218,11 @@ class TemporalEngine:
                     f"program {s.program.name!r} has no init; pass x0"
                 x0 = s.program.init(self.bg)
             x0 = jnp.asarray(x0, jnp.float32)
+            if self.mesh is not None:
+                # ([Q,] P, Vp): the state's partitions over the model axes
+                lead = [None] * (x0.ndim - 2)
+                x0 = jax.device_put(x0, NamedSharding(
+                    self.mesh, PartitionSpec(*lead, self._maxes)))
             if self.parts is not None:
                 # x0 is always FULL-width ([Q,] P, Vp) — program inits and
                 # resume_seed scatter globally; the shard keeps its rows
@@ -1274,6 +1337,13 @@ class TemporalEngine:
             return out.reshape(lead_shape + out.shape[-1:])
 
         values = gather(xs)
+        # one exchange per superstep; a query axis runs every lane until
+        # the slowest converges, each superstep moving all Q lanes
+        ss_np = np.asarray(ss)
+        steps = int(ss_np.sum()) if ss_np.ndim < 2 \
+            else int(ss_np.max(axis=0).sum()) * ss_np.shape[0]
+        self.exchange_bytes += int(round(
+            steps * self.exchange_bytes_per_superstep))
         return EngineResult(
             pattern=pattern,
             values=values,
@@ -1283,7 +1353,7 @@ class TemporalEngine:
             merged=gather(merged)
             if (pattern == "eventually" and merge == "mean") else None,
             stats={
-                "supersteps": np.asarray(ss),
+                "supersteps": ss_np,
                 "local_sweeps": np.asarray(lsw),
             },
             occupancy=occ,

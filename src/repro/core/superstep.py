@@ -238,7 +238,10 @@ def _publish(x: jax.Array, dg: DeviceGraph, sr: Semiring,
         return sr.scatter_add(buf, slots, vals)
 
     buf = jax.vmap(one)(x, dg.out_slot, dg.out_local, dg.out_mask)
-    return comm.combine_boundary(buf, sr)
+    # on a mesh this is the superstep's collective: its ops carry the
+    # scope's name in their HLO metadata
+    with jax.named_scope("gopher.exchange"):
+        return comm.combine_boundary(buf, sr)
 
 
 def _consume(

@@ -18,11 +18,12 @@ knob        rule
 layout      recorded/measured tile occupancy ``<= 25%`` -> ``sparse``
             (the `BENCH_temporal.json` crossover); above, or unknown
             without reading values -> ``dense`` (always correct)
-comm        mesh given -> ``repro.launch.mesh.recommended_comm`` with the
-            REAL cut (``boundary_nnz``): DCI exchange axes and a large
-            cut -> ``ring``, else ``dense``; no mesh -> ``dense`` (the
-            stacked in-process fold; ``"host"`` targets mesh-free
-            multi-process clusters and stays an explicit override)
+comm        mesh given -> ``repro.launch.mesh.recommended_comm`` with
+            the REAL cut (``boundary_nnz``): DCI exchange axes and a
+            large cut -> ``ring``, else ``dense``; the planner's own mesh
+            (one host's ICI) or stacked -> ``dense`` (the stacked
+            in-process fold; ``"host"`` targets mesh-free multi-process
+            clusters and stays an explicit override)
 staging     store-backed analytics -> ``async`` (slice reads overlap
             execution), including derived weights whose transform is
             declared ``rowwise`` (applied chunk-wise on the prefetch
@@ -46,9 +47,13 @@ kernel      jax backend not ``tpu`` -> ``off`` (the jnp oracle path IS
             tile DMA, and folds the halt vote in-kernel); ``tpu``
             otherwise -> ``spmv`` (per-stage SpMV kernel; dense template
             walks gain little from fusing the vote)
-placement   mesh given -> shard partitions over ``model_axes`` and
-            temporally concurrent instances over ``data_axis``;
-            else stacked
+placement   mesh given -> that mesh (an override): partitions over
+            ``model_axes``, temporally concurrent instances over
+            ``data_axis``; no mesh and a TPU backend of more than one
+            chip (``repro.launch.mesh.planner_devices``) ->
+            ``propose_mesh_split``'s (data, model) mesh over every chip,
+            which the session builds; otherwise (one chip, the CPU
+            backend, forced host devices) -> stacked
 ==========  ==============================================================
 """
 from __future__ import annotations
@@ -78,6 +83,15 @@ class PlanChoice:
 
     def __str__(self) -> str:
         return f"{self.value} [{self.source}] {self.reason}"
+
+
+@dataclass(frozen=True)
+class Placement(PlanChoice):
+    """The placement knob; ``split`` is the (data, model) shape of the
+    mesh the planner chose, which the session builds (``None`` when the
+    plan runs stacked or on the caller's mesh)."""
+
+    split: Optional[Tuple[int, int]] = None
 
 
 def choice(value: Any, reason: str) -> PlanChoice:
@@ -123,7 +137,7 @@ class ExecutionPlan:
     delta: PlanChoice  # True | False — delta-chain tile staging
     warm: PlanChoice  # True | False — warm-started fixpoints
     kernel: PlanChoice  # "off" | "spmv" | "fused" — Pallas kernel mode
-    placement: PlanChoice  # "stacked" | mesh descriptor string
+    placement: Placement  # "stacked" | mesh descriptor string
     estimates: Tuple[Tuple[str, Any], ...]  # cost-model outputs, sorted
 
     @property
@@ -277,8 +291,9 @@ def propose_mesh_split(
 
     ``sequential`` chains instances, so the data axis is off the table
     and the proposal is all-model.  Returns ``{"data", "model",
-    "exchange_bytes_per_device", "why"}``; callers embed it in plan
-    estimates (``explain()`` renders it).
+    "devices", "exchange_bytes_per_device", "why"}``; the planner applies
+    it when no mesh is given and embeds it in plan estimates
+    (``explain()`` renders it).
 
     >>> p = propose_mesh_split(8, 16, 8, "independent",
     ...                        num_boundary=128, boundary_nnz=64)
@@ -310,7 +325,8 @@ def propose_mesh_split(
         # nothing divides: stack everything (the engine replicates
         # instances when the axis does not divide — correct, no speedup)
         return {
-            "data": 1, "model": 1, "exchange_bytes_per_device": 0.0,
+            "data": 1, "model": 1, "devices": D,
+            "exchange_bytes_per_device": 0.0,
             "why": f"no divisor split of {D} device(s) fits "
                    f"{n_parts} partitions x {num_instances} instances — "
                    f"run stacked/replicated",
@@ -330,7 +346,7 @@ def propose_mesh_split(
                f"{m}-way partition split moves "
                f"~{ex['bytes_per_device']:,.0f} B/device/superstep")
     return {
-        "data": int(d), "model": int(m),
+        "data": int(d), "model": int(m), "devices": D,
         "exchange_bytes_per_device": float(ex["bytes_per_device"]),
         "why": why,
     }
@@ -359,6 +375,7 @@ def plan_analytic(
     warm: Optional[bool] = None,
     kernel: Optional[str] = None,
     backend: Optional[str] = None,
+    devices: Optional[int] = None,
 ) -> ExecutionPlan:
     """Resolve every knob for one analytic (see module docstring rules).
 
@@ -373,9 +390,14 @@ def plan_analytic(
     ``backend`` — the jax platform the session dispatches to (the
     session passes ``repro.kernels.semiring_spmm.ops.resolved_backend``'s
     cached probe); it drives the ``kernel`` knob's auto rule.  ``None``
-    is treated as not-TPU (kernel off)."""
+    is treated as not-TPU (kernel off).
+
+    ``devices`` — how many chips a placement of the planner's own may
+    span when ``mesh`` is None; ``None`` asks
+    ``repro.launch.mesh.planner_devices`` (a TPU backend's chip count,
+    else 1)."""
     from repro.dist.collectives import boundary_exchange_bytes
-    from repro.launch.mesh import recommended_comm
+    from repro.launch.mesh import planner_devices, recommended_comm
 
     pattern = pattern or analytic.pattern
     assert pattern in ("sequential", "independent", "eventually"), pattern
@@ -402,18 +424,57 @@ def plan_analytic(
                      f"{SPARSE_OCCUPANCY_MAX:.0%} — packing would buy "
                      f"little over template tiles")
 
-    # ---- comm ------------------------------------------------------------
+    # ---- placement, and the comm backend it implies --------------------
+    # the mesh-shape proposal: how the chips SHOULD split between the
+    # instance (data) and partition (model) axes.  With no mesh given the
+    # planner applies it over every chip it may use (the session builds
+    # that mesh); with the caller's mesh it reviews the caller's split.
     nnz = int(bg.boundary_nnz)
+
+    def split_for(n: int, cm_name: str) -> Dict[str, Any]:
+        return propose_mesh_split(
+            n, num_instances, bg.n_parts, pattern,
+            num_boundary=bg.num_boundary, boundary_nnz=nnz, comm=cm_name)
+
+    own = None  # the (data, model) split of the planner's own mesh
+    if mesh is None:
+        if devices is None:
+            devices = planner_devices()
+        # 'host' is mesh-free
+        split = split_for(devices if comm != "host" else 1, comm or "dense")
+        if split["data"] * split["model"] > 1:
+            own = (split["data"], split["model"])
+
     if comm is not None:
         cm = override(comm)
-    elif mesh is None:
-        cm = choice("dense", "stacked in-process fold (no mesh; 'host' "
-                             "targets mesh-free multi-process clusters)")
-    else:
+    elif mesh is not None:
         rec = recommended_comm(mesh, model_axes, boundary_nnz=nnz)
         cm = choice(rec,
                     f"recommended_comm over exchange axes {model_axes} "
                     f"with boundary_nnz={nnz}")
+    elif own is not None:
+        cm = choice("dense", "the planner's mesh spans one host's ICI, "
+                             "where the all-reduce is latency-optimal")
+    else:
+        cm = choice("dense", "stacked in-process fold (no mesh; 'host' "
+                             "targets mesh-free multi-process clusters)")
+
+    if mesh is not None:
+        shape = dict(zip(mesh.axis_names, mesh.shape.values())) \
+            if hasattr(mesh.shape, "values") else dict(mesh.shape)
+        split = split_for(int(np.prod(list(shape.values()))), cm.value)
+        pl = Placement(f"mesh{shape}", "override",
+                       f"caller's mesh: partitions over {model_axes}; "
+                       f"temporally concurrent patterns shard instances "
+                       f"over the data axis")
+    elif own is not None:
+        pl = Placement(f"mesh{{'data': {own[0]}, 'model': {own[1]}}}",
+                       "auto", f"{devices} chips, no mesh given: "
+                       f"{split['why']}", split=own)
+    else:
+        pl = Placement("stacked", "auto",
+                       "partitions stacked on one device, instances scanned"
+                       + ("" if devices <= 1 else f" ({split['why']})"))
 
     # ---- staging ---------------------------------------------------------
     if staging is not None:
@@ -518,17 +579,6 @@ def plan_analytic(
                     "tpu, dense-regime tiles — per-stage SpMV kernel; "
                     "template walks gain little from fusing the vote")
 
-    # ---- placement -------------------------------------------------------
-    if mesh is None:
-        pl = choice("stacked", "no mesh — partitions stacked on one "
-                               "device, instances scanned")
-    else:
-        shape = dict(zip(mesh.axis_names, mesh.shape.values())) \
-            if hasattr(mesh.shape, "values") else dict(mesh.shape)
-        pl = choice(f"mesh{shape}",
-                    f"partitions over {model_axes}; temporally concurrent "
-                    f"patterns shard instances over the data axis")
-
     # ---- estimates -------------------------------------------------------
     # query axis: a sequence on the analytic's source parameter widens the
     # semiring state to (Q, P, Vp) — Q requests in one engine pass whose
@@ -550,20 +600,6 @@ def plan_analytic(
         # payload once, priced against the reconstructed sparse batch
         base = sparse_bytes if sparse_bytes is not None else dense_bytes
         source_bytes_delta = int(round(base * delta_ratio))
-    # mesh-shape proposal: how the available device pool SHOULD split
-    # between the instance (data) and partition (model) axes — advisory
-    # when no mesh was given, a review of the split when one was
-    if mesh is not None:
-        num_devices = 1
-        for n in shape.values():
-            num_devices *= int(n)
-    else:
-        import jax
-
-        num_devices = jax.local_device_count()
-    split = propose_mesh_split(
-        num_devices, num_instances, bg.n_parts, pattern,
-        num_boundary=bg.num_boundary, boundary_nnz=nnz, comm=cm.value)
     estimates = {
         "num_vertices": int(len(bg.part_of)),
         "num_instances": int(num_instances),
@@ -581,7 +617,7 @@ def plan_analytic(
         "exchange_kind": ex["kind"],
         "exchange_hops": int(ex["hops"]),
         "exchange_bytes_per_device": float(ex["bytes_per_device"]),
-        "mesh_split_devices": int(num_devices),
+        "mesh_split_devices": int(split["devices"]),
         "mesh_split_data": split["data"],
         "mesh_split_model": split["model"],
         "mesh_split_why": split["why"],

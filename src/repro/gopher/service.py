@@ -524,7 +524,7 @@ class GopherService:
             "staging_cache": self.session.staging_cache_stats(),
             "subscriptions": len(self._subs),
             "appends_observed": self._appends_observed,
-            **self.session.runner_stats(),
+            **self.session.engine_counters(),
         }
 
 

@@ -10,8 +10,10 @@ three verbs:
   (:mod:`repro.gopher.registry`) into a costed
   :class:`~repro.gopher.planner.ExecutionPlan`: tile layout from the
   recorded occupancy, comm backend from the real cut size, staging mode
-  from the source, placement from the mesh — every choice overridable
-  and rendered by ``plan.explain()`` before anything runs.
+  from the source, placement from the chips it can see (a TPU of several
+  chips: the planner's mesh split, which the session builds; otherwise
+  stacked; ``mesh=`` overrides) — every choice overridable and rendered
+  by ``plan.explain()`` before anything runs.
 * ``run(plan)`` — execute one plan, returning an
   :class:`AnalyticResult` (the engine outputs + the plan that produced
   them).
@@ -63,6 +65,7 @@ from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.blocked import BlockedGraph, SparseBlocked, pow2_bucket
@@ -72,6 +75,8 @@ from repro.gopher.planner import (
 from repro.gopher.registry import Analytic, get_analytic
 
 ONES_ATTR = "__ones__"  # pseudo-attribute: unit weights on every edge
+# the jax.monitoring scalar each run_many publishes: its exchange_bytes
+EXCHANGE_BYTES_EVENT = "/gopher/exchange_bytes"
 
 
 # ---------------------------------------------------------------------------
@@ -270,8 +275,7 @@ class PlanContext:
         pattern = pattern or self.plan.pattern
         merge = merge if merge is not None else (
             self.plan.merge if pattern == "eventually" else None)
-        engine = self.session._engine(self.plan.graph, self.plan.comm.value,
-                                      self.plan.kernel.value)
+        engine = self.session._engine(self.plan)
         spec = RunSpec(program, pattern, x0=x0, merge=merge)
         return self.session._dispatch_specs(engine, [spec], staged)[0]
 
@@ -283,9 +287,11 @@ class PlanContext:
 class GopherSession:
     """Declarative session over one time-series graph collection.
 
-    See the module docstring for the data sources and verbs.  Placement
-    is session-level (``mesh``/``data_axis``/``model_axes``/
-    ``use_pallas``), analytics and their knobs are plan-level."""
+    See the module docstring for the data sources and verbs.  A caller's
+    ``mesh`` (with ``data_axis``/``model_axes``) and ``use_pallas`` are
+    session-level overrides; without a mesh each plan's placement is the
+    planner's, and engines are built per placement.  Analytics and their
+    knobs are plan-level."""
 
     def __init__(
         self,
@@ -335,7 +341,7 @@ class GopherSession:
         self.tsg: Optional[TimeSeriesGraph] = None
         self._weights = dict(weights or {})
         self._vertex_attrs = dict(vertex_attrs or {})
-        self._engines: Dict[Tuple[str, str, str], TemporalEngine] = {}
+        self._engines: Dict[Tuple, TemporalEngine] = {}
         self._bg_variants: Dict[str, BlockedGraph] = {}
         self._w_cache: Dict[Tuple, np.ndarray] = {}
         self._activity_cache: Dict[Tuple, Tuple] = {}
@@ -472,6 +478,8 @@ class GopherSession:
             layout=layout, comm=comm, staging=staging,
             delta=delta, warm=warm,
             kernel=kernel, backend=resolved_backend(),
+            # a cluster process is one shard of a stacked run
+            devices=1 if self.cluster is not None else None,
         )
 
     def explain(self, analytic: str, **kw) -> str:
@@ -517,14 +525,20 @@ class GopherSession:
         groups that is a single disk prefetch pass feeding N runners.
         Results come back in plan order, bitwise identical to running
         each plan alone; ``session.last_run_report`` records the staging
-        economy (bytes, passes)."""
+        economy (bytes, passes), the runner counters, the ``mesh`` the
+        (first) plan ran on (``{"data": d, "model": m}``; ``None``
+        stacked) and ``exchange_bytes``, the boundary bytes one device
+        sent over the call (0 stacked).  ``exchange_bytes`` is also
+        published as the ``jax.monitoring`` scalar
+        ``EXCHANGE_BYTES_EVENT``, so a listener can count it without
+        holding the session."""
         plans = list(plans)
         # session-lifetime cache when configured (warm serving), else one
         # cache per call; counters are cumulative so report deltas below
         cache = self._staging_cache if self._staging_cache is not None \
             else _StagingCache()
         base = (cache.staged_bytes, cache.staging_passes, cache.hits)
-        runners = self.runner_stats()
+        counters = self.engine_counters()
         results: List[Optional[AnalyticResult]] = [None] * len(plans)
         resolved = [get_analytic(p.analytic) for p in plans]
 
@@ -541,15 +555,15 @@ class GopherSession:
         for i, (a, p) in enumerate(zip(resolved, plans)):
             if not a.composite:
                 key = self._main_key(a, p.layout.value) + (
-                    p.comm.value, p.kernel.value)
+                    p.comm.value, p.kernel.value, p.placement.split)
                 groups.setdefault(key, []).append(i)
-        # a staging key split across comm/kernel backends must stage via
+        # a staging key split across comm/kernel/placement must stage via
         # the cache (a private stream per group would re-read the disk)
         skey_groups: Dict[Tuple, int] = {}
         for key in groups:
-            skey_groups[key[:-2]] = skey_groups.get(key[:-2], 0) + 1
+            skey_groups[key[:-3]] = skey_groups.get(key[:-3], 0) + 1
         for key, idxs in groups.items():
-            skey, comm, kern = key[:-2], key[-2], key[-1]
+            skey = key[:-3]
             graph, attr, transform, zero, layout = skey
             specs = []
             for i in idxs:
@@ -559,7 +573,7 @@ class GopherSession:
                 specs.append(RunSpec(program, plans[i].pattern,
                                      merge=plans[i].merge,
                                      warm_start=bool(plans[i].warm.value)))
-            engine = self._engine(graph, comm, kern)
+            engine = self._engine(plans[idxs[0]])
             a0 = resolved[idxs[0]]
             # row-wise transforms stream too: the derived weights compute
             # chunk-by-chunk on the prefetch pool (registry `rowwise`)
@@ -614,17 +628,32 @@ class GopherSession:
             "cache_hits": cache.hits - base[2],
             "resident_bytes": cache.resident_bytes,
             "analytics": [p.analytic for p in plans],
-            **{k: v - runners[k] for k, v in self.runner_stats().items()},
+            "mesh": self._mesh_shape(plans[0]) if plans else None,
+            **{k: v - counters[k]
+               for k, v in self.engine_counters().items()},
         }
+        jax.monitoring.record_scalar(
+            EXCHANGE_BYTES_EVENT, self.last_run_report["exchange_bytes"])
         return results  # type: ignore[return-value]
 
-    def runner_stats(self) -> Dict[str, int]:
-        """Cumulative runner counters over the session's engines:
-        ``runner_builds`` (calls that traced and built a program) and
-        ``runner_hits`` (calls that reused a built one)."""
+    def engine_counters(self) -> Dict[str, int]:
+        """Cumulative counters over the session's engines:
+        ``runner_builds`` (calls that traced and built a program),
+        ``runner_hits`` (calls that reused a built one) and
+        ``exchange_bytes`` (boundary bytes one device sent)."""
         engines = tuple(self._engines.values())  # read from other threads
         return {"runner_builds": sum(e.runner_builds for e in engines),
-                "runner_hits": sum(e.runner_hits for e in engines)}
+                "runner_hits": sum(e.runner_hits for e in engines),
+                "exchange_bytes": sum(e.exchange_bytes for e in engines)}
+
+    def _mesh_shape(self, plan: ExecutionPlan) -> Optional[Dict[str, int]]:
+        """The shape of the mesh ``plan`` runs on; ``None`` stacked."""
+        if self.mesh is not None:
+            return {a: int(n) for a, n in self.mesh.shape.items()}
+        if plan.placement.split is None:
+            return None
+        d, m = plan.placement.split
+        return {"data": d, "model": m}
 
     def staging_cache_stats(self) -> Optional[Dict[str, Any]]:
         """Cumulative counters of the session-lifetime staging cache
@@ -782,8 +811,7 @@ class GopherSession:
             else _StagingCache()
         ctx = PlanContext(self, plan, a, cache)
         program = a.make_program(ctx, **plan.param_dict)
-        engine = self._engine(plan.graph, plan.comm.value,
-                              plan.kernel.value)
+        engine = self._engine(plan)
         warm = bool(plan.warm.value) and program.kind == "fixpoint"
         if plan.pattern == "sequential":
             spec = RunSpec(program, plan.pattern,
@@ -836,9 +864,12 @@ class GopherSession:
         return engine.run_many(specs, tiles=staged.tiles,
                                btiles=staged.btiles)
 
-    def _engine(self, graph: str, comm: str,
-                kernel: str = "off") -> TemporalEngine:
-        key = (graph, comm, kernel)
+    def _engine(self, plan: ExecutionPlan) -> TemporalEngine:
+        """The engine of ``plan``'s graph, comm backend, kernel and
+        placement (built once per combination)."""
+        graph, comm, kernel = (plan.graph, plan.comm.value,
+                               plan.kernel.value)
+        key = (graph, comm, kernel, plan.placement.split)
         if key not in self._engines:
             # the plan's kernel knob already folded in any session-wide
             # use_pallas override; a (mode, interpret) tuple additionally
@@ -846,9 +877,17 @@ class GopherSession:
             up = kernel
             if isinstance(self.use_pallas, tuple):
                 up = (kernel, self.use_pallas[1])
+            mesh, data_axis, model_axes = (self.mesh, self.data_axis,
+                                           self.model_axes)
+            if plan.placement.split is not None:
+                from repro.launch.mesh import make_mesh
+
+                mesh = make_mesh(*plan.placement.split)
+                data_axis, model = mesh.axis_names
+                model_axes = (model,)
             self._engines[key] = TemporalEngine(
-                self._blocked(graph), mesh=self.mesh,
-                data_axis=self.data_axis, model_axes=self.model_axes,
+                self._blocked(graph), mesh=mesh,
+                data_axis=data_axis, model_axes=model_axes,
                 use_pallas=up, comm=comm, cluster=self.cluster,
             )
         return self._engines[key]

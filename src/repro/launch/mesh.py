@@ -26,10 +26,24 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     return jax.make_mesh(shape, axes)
 
 
-def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
-    """Small mesh over host devices for CPU tests (needs
+def make_mesh(data: int = 1, model: int = 1) -> Mesh:
+    """The (data, model) mesh over the default backend's first
+    ``data * model`` devices: the mesh a ``GopherSession`` builds for a
+    plan whose placement the planner chose (on the CPU backend it needs
     XLA_FLAGS=--xla_force_host_platform_device_count >= data*model)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         devices=jax.devices()[:data * model])
+
+
+def planner_devices() -> int:
+    """How many chips the planner may spread a plan over when the caller
+    gives no mesh: every device of a TPU backend driven by one process,
+    else 1.  The CPU backend (forced host devices included) and
+    multi-process runs stay stacked."""
+    devs = jax.devices()
+    if devs[0].platform != "tpu" or jax.process_count() > 1:
+        return 1
+    return len(devs)
 
 
 def mesh_dp_axes(mesh: Mesh) -> Tuple[str, ...]:
