@@ -21,7 +21,7 @@ package is that deployment layer for the blocked engine:
 from repro.cluster.checkpoint import AnalyticCheckpointer, ResumableRun
 from repro.cluster.gather import ClusterGather
 from repro.cluster.runtime import ClusterRuntime, init_cluster
-from repro.cluster.staging import shard_staged_bytes, shard_stream
+from repro.cluster.staging import shard_stream
 
 __all__ = [
     "AnalyticCheckpointer",
@@ -29,6 +29,5 @@ __all__ = [
     "ClusterRuntime",
     "ResumableRun",
     "init_cluster",
-    "shard_staged_bytes",
     "shard_stream",
 ]

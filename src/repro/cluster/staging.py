@@ -24,9 +24,9 @@ stages exactly that range:
   runs on the CONSUMER thread, never the prefetch pool, so its exchange
   operations interleave deterministically with the engine's
   per-superstep exchanges.
-* :func:`shard_staged_bytes` is the accounting hook the CI lane and the
-  ``cluster_scaling`` bench row assert on: bytes materialized for a
-  chunk (tile tensors + sparse index arrays).
+* :attr:`ShardStream.staged_bytes` is the accounting the CI lane and the
+  ``cluster_scaling`` bench row assert on: bytes the host staged for the
+  chunks (``StagedChunk.nbytes``: tile tensors + sparse index arrays).
 """
 from __future__ import annotations
 
@@ -36,15 +36,6 @@ import numpy as np
 
 from repro.cluster.runtime import ClusterRuntime
 from repro.gofs.prefetch import SlicePrefetcher, StagedChunk
-
-
-def shard_staged_bytes(chunk: StagedChunk) -> int:
-    """Bytes materialized for one staged chunk (tiles + index arrays)."""
-    total = chunk.tiles.nbytes + chunk.btiles.nbytes
-    for a in (chunk.rows, chunk.cols, chunk.brows, chunk.bcols):
-        if a is not None:
-            total += a.nbytes
-    return total
 
 
 class ShardStream:
@@ -72,7 +63,7 @@ class ShardStream:
                     (int(ch.start), int(ch.count),
                      "sparse" if ch.is_sparse else "dense"),
                 )
-            self.staged_bytes += shard_staged_bytes(ch)
+            self.staged_bytes += ch.nbytes
             self.chunks += 1
             yield ch
 

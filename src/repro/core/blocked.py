@@ -212,20 +212,36 @@ class BlockedGraph:
         m = (part >= lo) & (part < hi)
         return part[m] - lo, flat[m], edge_id[m]
 
+    def fill_map(
+        self, boundary: bool, parts: Optional[Tuple[int, int]] = None,
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """The instance-invariant fill map of the local (``boundary=False``)
+        or boundary tiles: ``(slot, edge_id)``, where an instance's value
+        of template edge ``edge_id[k]`` lands at flat index ``slot[k]`` of
+        that instance's ``(P, T|Tb, B, B)`` tile set.  ``parts`` restricts
+        the map to a half-open partition range, rebased to it.  The host
+        fills below and the engine's device fill scatter through it."""
+        if boundary:
+            part, flat, edge_id, t_count = (self.re_part, self.re_flat,
+                                            self.re_edge_id, self.tb_max)
+        else:
+            part, flat, edge_id, t_count = (self.le_part, self.le_flat,
+                                            self.le_edge_id, self.t_max)
+        if parts is not None:
+            part, flat, edge_id = self._part_filter(parts, part, flat,
+                                                    edge_id)
+        return self._slot_key(part, flat, t_count), edge_id
+
     def _fill_batch(
-        self, weights: np.ndarray, zero: float, part: np.ndarray,
-        flat: np.ndarray, edge_id: np.ndarray, t_count: int,
+        self, weights: np.ndarray, zero: float, boundary: bool,
         out: Optional[np.ndarray], slots_unique: bool,
         parts: Optional[Tuple[int, int]] = None,
     ) -> np.ndarray:
         B = self.block_size
         I = weights.shape[0]
-        if parts is not None:
-            part, flat, edge_id = self._part_filter(parts, part, flat,
-                                                    edge_id)
-            P = parts[1] - parts[0]
-        else:
-            P = self.n_parts
+        t_count = self.tb_max if boundary else self.t_max
+        P = self.n_parts if parts is None else parts[1] - parts[0]
+        slot, edge_id = self.fill_map(boundary, parts)
         per_inst = P * t_count * B * B
         if out is None:
             vals = np.full(I * per_inst, zero, np.float32)
@@ -235,7 +251,6 @@ class BlockedGraph:
             assert out.dtype == np.float32 and out.flags.c_contiguous
             vals = out.reshape(-1)
             vals[...] = zero
-        slot = part.astype(np.int64) * (t_count * B * B) + flat
         idx = (np.arange(I, dtype=np.int64)[:, None] * per_inst + slot[None, :])
         if slots_unique:
             # no parallel edges share a slot: semiring combining is a
@@ -274,10 +289,8 @@ class BlockedGraph:
         call when the prefetcher stages chunk buffers.  ``parts``: fill
         only the half-open partition range (shard-local staging) — the
         result's partition axis is ``hi - lo``."""
-        return self._fill_batch(
-            weights, zero, self.le_part, self.le_flat, self.le_edge_id,
-            self.t_max, out, self._local_slots_unique(), parts=parts,
-        )
+        return self._fill_batch(weights, zero, False, out,
+                                self._local_slots_unique(), parts=parts)
 
     def fill_boundary_batch(
         self, weights: np.ndarray, zero: float = INF,
@@ -287,10 +300,8 @@ class BlockedGraph:
         """Instance edge weights (I, E) -> boundary tiles (I, P, Tb, B, B).
 
         ``out``/``parts``: as in ``fill_local_batch``."""
-        return self._fill_batch(
-            weights, zero, self.re_part, self.re_flat, self.re_edge_id,
-            self.tb_max, out, self._boundary_slots_unique(), parts=parts,
-        )
+        return self._fill_batch(weights, zero, True, out,
+                                self._boundary_slots_unique(), parts=parts)
 
     def alloc_batch_buffers(
         self, max_instances: int, *,

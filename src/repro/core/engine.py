@@ -58,7 +58,9 @@ SpMV.
 Staging can also be *overlapped* with execution (``staging="async"`` or an
 explicit ``stream=``): chunks of instances arrive from a
 :class:`repro.gofs.prefetch.SlicePrefetcher` double-buffer while the device
-executes the previous chunk — the paper's §V storage/compute overlap.  See
+executes the previous chunk — the paper's §V storage/compute overlap.  A
+dense chunk arrives as ``(I_c, E)`` edge rows, and the engine scatters them
+into its tiles on the device (per model shard on a mesh).  See
 ``TemporalEngine`` and ``docs/ARCHITECTURE.md`` for the pipeline diagram.
 
 Stats are reported in the same :class:`repro.core.ibsp.BSPStats` shape as
@@ -584,7 +586,12 @@ class TemporalEngine:
         self._built: set = set()
         self.runner_builds = 0
         self.runner_hits = 0
+        self.device_fills = 0  # streamed chunks whose tiles the device filled
         self.exchange_bytes = 0  # cumulative, over this engine's runs
+        # device fill (see _device_fill): the slot maps, uploaded on first
+        # use, and one jitted fill per (zero, instance axis)
+        self._fill_maps_dev: Optional[Tuple[jax.Array, ...]] = None
+        self._fill_fns: Dict[Any, Callable] = {}
         self._merge_fns: Dict[int, Callable] = {}
         # staged-batch device cache: host-array identity (weakly held) ->
         # device arrays (see _cached_device) so repeated runs over one
@@ -643,6 +650,12 @@ class TemporalEngine:
                 return tuple(_device_put(a) for a in host_arrays)
             return tuple(_device_put(a, sh) for a in host_arrays)
 
+    def _model_size(self) -> int:
+        """Devices over the model axes (1 stacked)."""
+        if self.mesh is None:
+            return 1
+        return int(np.prod([self.mesh.shape[a] for a in self.model_axes]))
+
     @property
     def exchange_bytes_per_superstep(self) -> float:
         """Boundary bytes one device sends in one superstep's exchange
@@ -651,10 +664,122 @@ class TemporalEngine:
         where the fold never leaves the device."""
         if self.mesh is None:
             return 0.0
-        m = int(np.prod([self.mesh.shape[a] for a in self.model_axes]))
         return float(boundary_exchange_bytes(
-            self.bg.num_boundary, m, self.comm.name,
+            self.bg.num_boundary, self._model_size(), self.comm.name,
             boundary_nnz=int(self.bg.boundary_nnz))["bytes_per_device"])
+
+    # -------------------------------------------------------- device fill
+    def _shard_parts(self) -> List[Tuple[int, int]]:
+        """The partition range of each model shard, in mesh order: one
+        range stacked (a cluster shard's own), contiguous blocks of
+        ``P / m`` over the ``m`` devices of the model axes on a mesh."""
+        if self.mesh is None:
+            return [self.parts or (0, self.bg.n_parts)]
+        m = self._model_size()
+        assert self.bg.n_parts % m == 0, (self.bg.n_parts, m)
+        pl = self.bg.n_parts // m
+        return [(j * pl, (j + 1) * pl) for j in range(m)]
+
+    def _fill_maps(self) -> Tuple[jax.Array, ...]:
+        """Device copies of the instance-invariant fill maps, built once:
+        (local slot, local edge id, boundary slot, boundary edge id), each
+        ``(m, L)`` int32 with one row per model shard from
+        ``BlockedGraph.fill_map`` over that shard's partition range.  Rows
+        are padded to one length with distinct slots past the shard's
+        tiles, which the scatter drops; on a mesh row ``j`` lives on model
+        shard ``j``."""
+        if self._fill_maps_dev is None:
+            B2 = self.bg.block_size ** 2
+            maps = []
+            for boundary, t_count in ((False, self.bg.t_max),
+                                      (True, self.bg.tb_max)):
+                per = [self.bg.fill_map(boundary, r)
+                       for r in self._shard_parts()]
+                lo, hi = self._shard_parts()[0]
+                size = (hi - lo) * t_count * B2  # one instance's slots
+                width = max(len(sl) for sl, _ in per)
+                assert size + width < 2 ** 31, "slot overflows int32"
+                slot = np.empty((len(per), width), np.int32)
+                eid = np.zeros((len(per), width), np.int32)
+                for j, (sl, ei) in enumerate(per):
+                    slot[j, :len(sl)] = sl
+                    slot[j, len(sl):] = size + np.arange(width - len(sl))
+                    eid[j, :len(ei)] = ei
+                maps += [slot, eid]
+            sh = None if self.mesh is None else \
+                NamedSharding(self.mesh, PartitionSpec(self._maxes))
+            self._fill_maps_dev = tuple(
+                jnp.asarray(a) if sh is None else jax.device_put(a, sh)
+                for a in maps)
+        return self._fill_maps_dev
+
+    def _make_fill(self, zero: float, iaxis):
+        """The jitted device fill of one chunk: edge rows ``(n, E)`` and
+        the fill maps -> (tiles, btiles), equal to the host's
+        ``fill_local_batch`` / ``fill_boundary_batch``.  Every slot starts
+        at ``zero``; edges scatter with ``.set`` when no two share a slot,
+        else combine with the semiring add (``.min`` for an INF zero,
+        ``.add`` otherwise), as the host fill does.  The instance is an
+        index coordinate of its own (the slot map broadcasts over it),
+        never ``instance * slots + slot``, so no coordinate outgrows one
+        shard's tile set, and int32 holds at any chunk length.  Stacked
+        (and a cluster shard) is the one-shard case; on a mesh each model
+        shard fills its own partitions under ``shard_map``, laid out as
+        ``_put`` would upload them."""
+        bg = self.bg
+        B = bg.block_size
+        lo, hi = self._shard_parts()[0]
+        pl = hi - lo  # partitions a shard fills
+        combine = "min" if zero == INF else "add"
+        ops = ("set" if bg._local_slots_unique() else combine,
+               "set" if bg._boundary_slots_unique() else combine)
+
+        def scatter(rows, slot, eid, t_count, op):
+            n, s = rows.shape[0], slot[0]
+            # one (instance, row, lane) coordinate per update: the rows of
+            # B lanes keep the tiles' device layout, so the scatter writes
+            # in place and the reshape is free
+            at = tuple(jnp.broadcast_to(a, (n, s.shape[0])) for a in (
+                jnp.arange(n)[:, None], (s // B)[None, :], (s % B)[None, :]))
+            vals = jnp.full((n, pl * t_count * B, B), zero, jnp.float32)
+            vals = getattr(vals.at[at], op)(rows[:, eid[0]], mode="drop",
+                                            unique_indices=op == "set")
+            return vals.reshape(n, pl, t_count, B, B)
+
+        def fill(rows, lslot, leid, bslot, beid):
+            return (scatter(rows, lslot, leid, bg.t_max, ops[0]),
+                    scatter(rows, bslot, beid, bg.tb_max, ops[1]))
+
+        if self.mesh is None:
+            return jax.jit(fill)
+        maxes = PartitionSpec(self._maxes)
+        return jax.jit(jax.shard_map(
+            fill, mesh=self.mesh,
+            in_specs=(PartitionSpec(iaxis),) + (maxes,) * 4,
+            out_specs=(PartitionSpec(iaxis, self._maxes),) * 2,
+            check_vma=False,
+        ))
+
+    def _device_fill(self, ch, iaxis=None) -> Tuple[jax.Array, jax.Array]:
+        """Upload one chunk's edge rows and fill its tiles on the device
+        (one ``engine.put`` span: the rows' upload and the fill's
+        dispatch); counted in ``device_fills``.  On a mesh the rows go to
+        every model shard (instances over ``iaxis``), and no chip holds
+        another's tiles."""
+        key = (float(ch.zero), iaxis)
+        fn = self._fill_fns.get(key)
+        if fn is None:
+            fn = self._fill_fns[key] = self._make_fill(*key)
+        with TraceAnnotation("engine.put"):
+            maps = self._fill_maps()
+            if self.mesh is None:
+                rows = _device_put(ch.edges)
+            else:
+                rows = _device_put(ch.edges, NamedSharding(
+                    self.mesh, PartitionSpec(iaxis)))
+            out = fn(rows, *maps)
+        self.device_fills += 1
+        return out
 
     def stage_sparse(
         self, instance_weights: np.ndarray, zero_fill: float
@@ -1015,6 +1140,9 @@ class TemporalEngine:
         eventually Merges fold once over the concatenated states.
         Sparse-layout chunks (packed tiles + per-instance index) dispatch
         through the sparse runners; dense chunks through the dense ones.
+        A dense chunk that carries edge rows (``StagedChunk.edges``) has
+        its tiles filled on the device (``_device_fill``); one that
+        carries tiles is uploaded as it is.
         Returns ([(xs, final, merged, ss, lsw)] per spec, occupancy | None).
         """
         N = len(specs)
@@ -1031,7 +1159,7 @@ class TemporalEngine:
             # zero-copy-aliases aligned host buffers on CPU and defers
             # the host read even under copy=True, so a reused staging
             # buffer would be overwritten mid-execution.
-            n = int(ch.tiles.shape[0])
+            n = int(ch.count)
             n_total += n
             is_sparse = bool(getattr(ch, "is_sparse", False))
             # cluster shards keep only their partition rows: chunks from a
@@ -1046,7 +1174,7 @@ class TemporalEngine:
                         ch.bcols)
                 tail = self._struct_tail
             else:
-                host = (ch.tiles, ch.btiles)
+                host = None if ch.edges is not None else (ch.tiles, ch.btiles)
                 tail = self._struct
             # on a mesh the chunk goes straight to its shards: partitions
             # over the model axes, instances over data when every spec's
@@ -1056,7 +1184,10 @@ class TemporalEngine:
                 axes = {self._instance_axis(s.pattern, s.effective_warm(), n)
                         for s in specs}
                 iaxis = axes.pop() if len(axes) == 1 else None
-            bufs = self._put([self._shard_axis(a) for a in host], iaxis)
+            if host is None:  # edge rows: the device fills the tiles
+                bufs = self._device_fill(ch, iaxis)
+            else:
+                bufs = self._put([self._shard_axis(a) for a in host], iaxis)
             for k, s in enumerate(specs):
                 warm_k = s.effective_warm()
                 # warm chunks chain exactly like sequential: the carry is

@@ -6,16 +6,20 @@ only as fast as the store can feed it time-series instances; its storage
 section overlaps slice materialization with computation so the engine never
 waits on disk.  :class:`SlicePrefetcher` is that pipeline for the blocked
 engine: it reads an edge attribute's (bin, pack) slices on a background
-thread pool, assembles them into ready ``(I_chunk, P, T, B, B)`` instance
-tile tensors (through the batched in-place ``BlockedGraph`` ``out=``
-fills), and hands chunks to the consumer through a bounded in-order
-window — the same shape as the shard prefetch in
-``repro.train.data.PackedShardDataset``.
+thread pool, applies the row-wise ``transform``, and hands chunks to the
+consumer through a bounded in-order window — the same shape as the shard
+prefetch in ``repro.train.data.PackedShardDataset``.  A dense chunk carries
+the ``(I_chunk, E)`` edge rows, and the engine scatters them into
+``(I_chunk, P, T, B, B)`` tiles on the device, so the host stages and
+uploads bytes that scale with edges, not with tiles.  A sparse chunk
+carries packed active tiles filled on the pool (the batched in-place
+``BlockedGraph`` fills), and so does a ``stage_fn`` chunk (delta chains,
+shard-local staging).
 
 ``prefetch_depth`` semantics:
 
-* ``1``  — degenerate/synchronous: no thread is created; each chunk is read
-  and filled on demand when the consumer asks for it.
+* ``1``  — degenerate/synchronous: no thread is created; each chunk is
+  staged on demand when the consumer asks for it.
 * ``d>=2`` — double (d=2) or deeper buffering: up to ``d - 1`` chunks are
   staged ahead on the pool while the consumer processes the current one.
 
@@ -59,7 +63,6 @@ from __future__ import annotations
 import threading
 from collections import deque
 from concurrent.futures import CancelledError, ThreadPoolExecutor
-from dataclasses import dataclass
 from typing import Callable, Iterator, List, Optional, Tuple
 
 import numpy as np
@@ -68,41 +71,90 @@ from jax.profiler import TraceAnnotation
 THREAD_PREFIX = "gofs-prefetch"
 
 
-@dataclass
 class StagedChunk:
     """A contiguous run of staged instances, ready for the engine.
 
-    The chunk owns ``tiles``/``btiles`` (and, for the block-sparse layout,
-    the tile-index arrays): the prefetcher never touches them again after
-    handoff, so consumers may alias them (``jnp.asarray``) for as long as
-    they hold the chunk.
+    The chunk owns its arrays: the prefetcher never touches them again
+    after handoff, so consumers may alias them (``jnp.asarray``) for as
+    long as they hold the chunk.
 
-    Dense layout: ``tiles``/``btiles`` span the full template tile axis
-    and the index fields are ``None``.  Sparse layout
+    Dense layout, edge rows (``edges`` set): the chunk carries the
+    instances' ``(count, E)`` float32 edge values, after the row-wise
+    transform, and the semiring ``zero``; the engine scatters them into
+    tiles on the device.  ``tiles``/``btiles`` are then filled on the host
+    on first access (``BlockedGraph.fill_local_batch`` /
+    ``fill_boundary_batch``) and kept, for host readers only.
+
+    Dense layout, tiles (a ``stage_fn`` such as the shard-local staging of
+    ``repro.cluster.staging``): ``tiles``/``btiles`` span the full
+    template tile axis and the index fields are ``None``.  Sparse layout
     (``repro.core.blocked.SparseBlocked`` fields): the tile axes are
     packed pow2 buckets and ``rows``/``cols``/``brows``/``bcols`` carry
     the per-instance active-tile index (``-1`` padding).
     """
 
-    start: int  # first (visible) instance index covered by this chunk
-    count: int
-    tiles: np.ndarray  # (count, P, T|K, B, B) local adjacency tiles
-    btiles: np.ndarray  # (count, P, Tb|Kb, B, B) boundary tiles
-    rows: Optional[np.ndarray] = None  # (count, P, K) int32, sparse only
-    cols: Optional[np.ndarray] = None  # (count, P, K)
-    brows: Optional[np.ndarray] = None  # (count, P, Kb)
-    bcols: Optional[np.ndarray] = None  # (count, P, Kb)
-    nnz: Optional[np.ndarray] = None  # (count, P) active local tiles
-    bnnz: Optional[np.ndarray] = None  # (count, P) active boundary tiles
-    # bytes materialized from the store for this chunk, when less than the
-    # arrays' nbytes — a delta-chain reconstruction decodes each unique
-    # tile payload once per chunk (GoFSStore.load_blocked_stream).  None =
-    # fully materialized.
-    staged_bytes: Optional[int] = None
+    def __init__(
+        self,
+        start: int,  # first (visible) instance index covered by this chunk
+        count: int,
+        tiles: Optional[np.ndarray] = None,  # (count, P, T|K, B, B) local
+        btiles: Optional[np.ndarray] = None,  # (count, P, Tb|Kb, B, B)
+        *,
+        rows: Optional[np.ndarray] = None,  # (count, P, K) int32, sparse
+        cols: Optional[np.ndarray] = None,  # (count, P, K)
+        brows: Optional[np.ndarray] = None,  # (count, P, Kb)
+        bcols: Optional[np.ndarray] = None,  # (count, P, Kb)
+        nnz: Optional[np.ndarray] = None,  # (count, P) active local tiles
+        bnnz: Optional[np.ndarray] = None,  # (count, P) active boundary
+        # bytes materialized from the store for this chunk, when less than
+        # the arrays' nbytes — a delta-chain reconstruction decodes each
+        # unique tile payload once per chunk
+        # (GoFSStore.load_blocked_stream).  None = fully materialized.
+        staged_bytes: Optional[int] = None,
+        edges: Optional[np.ndarray] = None,  # (count, E) float32 edge rows
+        zero: Optional[float] = None,  # semiring zero of the edge rows
+        bg=None,  # BlockedGraph the edge rows fill on the host
+    ):
+        assert (edges is not None) != (tiles is not None), \
+            "a chunk carries either edge rows or tiles"
+        self.start = start
+        self.count = count
+        self._tiles = tiles
+        self._btiles = btiles
+        self.rows, self.cols, self.brows, self.bcols = rows, cols, brows, bcols
+        self.nnz, self.bnnz = nnz, bnnz
+        self.staged_bytes = staged_bytes
+        self.edges = edges
+        self.zero = zero
+        self._bg = bg
+
+    @property
+    def tiles(self) -> np.ndarray:
+        if self._tiles is None:
+            self._tiles = self._bg.fill_local_batch(self.edges,
+                                                    zero=self.zero)
+        return self._tiles
+
+    @property
+    def btiles(self) -> np.ndarray:
+        if self._btiles is None:
+            self._btiles = self._bg.fill_boundary_batch(self.edges,
+                                                        zero=self.zero)
+        return self._btiles
 
     @property
     def is_sparse(self) -> bool:
         return self.rows is not None
+
+    @property
+    def nbytes(self) -> int:
+        """Bytes the host staged for the device: the edge rows, else the
+        tiles and the sparse index arrays.  Never fills lazy tiles."""
+        if self.edges is not None:
+            return int(self.edges.nbytes)
+        return int(sum(a.nbytes for a in (
+            self._tiles, self._btiles, self.rows, self.cols, self.brows,
+            self.bcols) if a is not None))
 
 
 # reader(start, end) -> (end - start, E) float32 edge weights for the
@@ -177,9 +229,9 @@ class SlicePrefetcher:
         self.bucket = bucket
         self.bbucket = bbucket
         # ``transform``: applied to each chunk's (n, E) rows on the POOL
-        # thread before the fill — row-wise derived weights (e.g.
-        # PageRank's outdegree normalization) stream chunk-wise instead of
-        # forcing a full (I, E) materialization up front.  Must be
+        # thread — row-wise derived weights (e.g. PageRank's outdegree
+        # normalization) stream chunk-wise instead of forcing a full
+        # (I, E) materialization up front.  Must be
         # per-instance independent: transform(w[s:e]) == transform(w)[s:e].
         # ``stage_fn``: replaces the read+fill entirely (e.g. the store's
         # delta-chain reconstruction); the windowing/cancellation machinery
@@ -211,8 +263,9 @@ class SlicePrefetcher:
         bucket: Optional[int] = None,
         bbucket: Optional[int] = None,
     ) -> "SlicePrefetcher":
-        """Prefetch from an in-memory (I, E) weight matrix (the fills —
-        the expensive host-side scatter — still overlap the engine run)."""
+        """Prefetch from an in-memory (I, E) weight matrix: dense chunks
+        are row slices of it, sparse chunks' packing (the expensive
+        host-side scatter) overlaps the engine run."""
         w = np.asarray(weights, np.float32)
         if w.ndim == 1:
             w = w[None]
@@ -229,9 +282,9 @@ class SlicePrefetcher:
 
     # ------------------------------------------------------------ staging
     def _stage(self, span: Tuple[int, int]) -> StagedChunk:
-        """Read + fill one chunk into chunk-owned buffers (runs on the
-        pool, so both the reads AND the fill/allocation overlap the
-        consumer's execution; on the caller when synchronous).  Spanned
+        """Read one chunk (and transform its rows, or fill its sparse
+        tiles) into chunk-owned buffers: on the pool, so the work overlaps
+        the consumer's execution; on the caller when synchronous.  Spanned
         as ``gofs.stage`` on the profiler's trace."""
         with TraceAnnotation("gofs.stage"):
             return self._fill(*span)
@@ -261,10 +314,11 @@ class SlicePrefetcher:
                 rows=rows, cols=cols, brows=brows, bcols=bcols,
                 nnz=nnz, bnnz=bnnz,
             )
-        lt_buf, bt_buf = self.bg.alloc_batch_buffers(n)
-        tiles = self.bg.fill_local_batch(w, zero=self.zero, out=lt_buf)
-        btiles = self.bg.fill_boundary_batch(w, zero=self.zero, out=bt_buf)
-        return StagedChunk(start=s, count=n, tiles=tiles, btiles=btiles)
+        # dense: the chunk ships its edge rows, and the engine fills the
+        # tiles on the device
+        return StagedChunk(start=s, count=n,
+                           edges=np.asarray(w, np.float32), zero=self.zero,
+                           bg=self.bg)
 
     def __iter__(self) -> Iterator[StagedChunk]:
         if self.prefetch_depth == 1:

@@ -113,7 +113,7 @@ class _StagingCache:
     def __init__(self, byte_budget: Optional[float] = None):
         self.entries: "OrderedDict[Tuple, StagedBatch]" = OrderedDict()
         self.byte_budget = byte_budget
-        self.staged_bytes = 0  # host tile/index bytes materialized (cum.)
+        self.staged_bytes = 0  # bytes the host staged for the device (cum.)
         self.staging_passes = 0  # distinct batch materializations (cum.)
         self.hits = 0  # re-staging avoided by residency (cum.)
         self.evictions = 0
@@ -639,11 +639,14 @@ class GopherSession:
     def engine_counters(self) -> Dict[str, int]:
         """Cumulative counters over the session's engines:
         ``runner_builds`` (calls that traced and built a program),
-        ``runner_hits`` (calls that reused a built one) and
-        ``exchange_bytes`` (boundary bytes one device sent)."""
+        ``runner_hits`` (calls that reused a built one),
+        ``device_fills`` (streamed chunks whose tiles the device filled
+        from edge rows) and ``exchange_bytes`` (boundary bytes one device
+        sent)."""
         engines = tuple(self._engines.values())  # read from other threads
         return {"runner_builds": sum(e.runner_builds for e in engines),
                 "runner_hits": sum(e.runner_hits for e in engines),
+                "device_fills": sum(e.device_fills for e in engines),
                 "exchange_bytes": sum(e.exchange_bytes for e in engines)}
 
     def _mesh_shape(self, plan: ExecutionPlan) -> Optional[Dict[str, int]]:
@@ -1105,20 +1108,15 @@ def _freeze_value(v) -> Any:
 
 
 def _counted_chunks(stream, cache: _StagingCache):
-    """Pass chunks through, accounting their staged bytes so streamed and
-    cached staging report comparably.  Delta-reconstructed chunks report
-    the bytes that actually moved from the store (``ch.staged_bytes``,
-    unique payloads only) rather than the reconstructed tensors."""
+    """Pass chunks through, accounting the bytes the host staged for the
+    device (``StagedChunk.nbytes``: a dense chunk's edge rows, else its
+    tiles and index arrays) so streamed and cached staging report
+    comparably.  Delta-reconstructed chunks report the bytes that
+    actually moved from the store (``ch.staged_bytes``, unique payloads
+    only) rather than the reconstructed tensors."""
     for ch in stream:
-        if ch.staged_bytes is not None:
-            cache.staged_bytes += int(ch.staged_bytes)
-            yield ch
-            continue
-        n = ch.tiles.nbytes + ch.btiles.nbytes
-        for a in (ch.rows, ch.cols, ch.brows, ch.bcols):
-            if a is not None:
-                n += a.nbytes
-        cache.staged_bytes += n
+        n = ch.nbytes if ch.staged_bytes is None else ch.staged_bytes
+        cache.staged_bytes += int(n)
         yield ch
 
 

@@ -94,6 +94,46 @@ def test_mesh_comm_backends_in_process(mesh_env, backend):
 
 
 @needs_devices
+def test_mesh_device_fill_holds_each_shards_partitions(mesh_env):
+    """The per-shard ``shard_map`` fill on {data 1, model 4}: every chip
+    holds the host fill's slice of its own partition, bitwise for an INF
+    zero (within 1e-6 for 0.0, whose shared slots sum in the scatter's
+    order); a streamed SSSP on the mesh equals the stacked run bitwise."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core.engine import (TemporalEngine, min_plus_program,
+                                   source_init)
+    from repro.gofs.prefetch import StagedChunk
+
+    tmpl, bg, wb, mesh = mesh_env
+    eng = TemporalEngine(bg, mesh=mesh)
+    w2 = np.ascontiguousarray(wb[:2], np.float32)
+    for zero in (np.inf, 0.0):
+        tiles, btiles = eng._device_fill(
+            StagedChunk(0, 2, edges=w2, zero=zero, bg=bg))
+        for got, ref in ((tiles, bg.fill_local_batch(w2, zero=zero)),
+                         (btiles, bg.fill_boundary_batch(w2, zero=zero))):
+            assert got.sharding.is_equivalent_to(
+                NamedSharding(mesh, P(None, "model")), got.ndim)
+            assert len(got.addressable_shards) == 4
+            for sh in got.addressable_shards:
+                assert sh.data.shape[1] == bg.n_parts // 4
+                if zero == np.inf:
+                    assert np.array_equal(np.asarray(sh.data), ref[sh.index])
+                else:
+                    np.testing.assert_allclose(np.asarray(sh.data),
+                                               ref[sh.index], atol=1e-6)
+    prog = min_plus_program("sssp", init=source_init(0), max_supersteps=16)
+    ref = TemporalEngine(bg).run(prog, wb[:4], pattern="sequential")
+    streamed = TemporalEngine(bg, mesh=mesh, staging="async",
+                              chunk_instances=2)
+    got = streamed.run(prog, wb[:4], pattern="sequential")
+    assert np.array_equal(ref.values, got.values)
+    assert np.array_equal(ref.stats["supersteps"], got.stats["supersteps"])
+    assert streamed.device_fills == 2
+
+
+@needs_devices
 def test_ring_rs_combine_parity_in_process():
     """RingExchange rs_ag vs circulate vs dense, raw combine_boundary
     under shard_map: min-plus bitwise, ragged and tiny buffer widths."""

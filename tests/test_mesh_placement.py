@@ -68,6 +68,7 @@ for name, (pm, ps) in plans.items():
         "exchange_bytes": sess.last_run_report["exchange_bytes"],
         "stacked_mesh": stacked.last_run_report["mesh"],
         "stacked_exchange_bytes": stacked.last_run_report["exchange_bytes"],
+        "device_fills": sess.last_run_report["device_fills"],
         "supersteps": int(rs.engine.stats["supersteps"].sum()),
         "same_supersteps": bool(np.array_equal(
             rm.engine.stats["supersteps"], rs.engine.stats["supersteps"])),
@@ -102,6 +103,20 @@ sync = sess.run(sess.plan("sssp", source=SRC, staging="sync"))
 out["sync_bitwise"] = bool(np.array_equal(
     sync.engine.values, stacked.run(plans["sssp"][1]).engine.values))
 out["boundary_nnz"] = int(bg.boundary_nnz)
+# the {data 1, model 4} engine's device fill of one chunk: each chip
+# holds the host fill's slice of its own partitions, nothing else
+from repro.gofs.prefetch import StagedChunk
+rows = store.edge_attr_rows("latency", range(4))
+tl, tb = sess._engine(plans["sssp"][0])._device_fill(
+    StagedChunk(0, 4, edges=rows, zero=INF, bg=bg))
+shards = []
+for got, ref in ((tl, bg.fill_local_batch(rows)),
+                 (tb, bg.fill_boundary_batch(rows))):
+    for sh in got.addressable_shards:
+        shards.append([sh.data.shape[1],
+                       bool(np.array_equal(np.asarray(sh.data),
+                                           ref[sh.index]))])
+out["fill_shards"] = shards
 print("RESULT " + json.dumps(out))
 """
 
@@ -164,3 +179,13 @@ def test_exchange_bytes_match_the_hand_count(result):
     assert pr["exchange_bytes"] == 0
     for name in ("sssp", "pagerank"):
         assert result[name]["stacked_exchange_bytes"] == 0
+
+
+def test_streamed_chunks_fill_on_their_shards(result):
+    """Each streamed chunk's tiles are filled on the device, per model
+    shard: two 4-instance chunks a pass on either derived mesh, and one
+    chunk's fill puts on each of the four chips exactly the host fill's
+    slice of its two partitions."""
+    for name in ("sssp", "pagerank"):
+        assert result[name]["device_fills"] == 2
+    assert result["fill_shards"] == [[2, True]] * 8

@@ -1,7 +1,12 @@
 """SlicePrefetcher + async engine staging: the double-buffered GoFS read
 pipeline must be invisible in the results — async-vs-sync staging parity is
 BITWISE on all three iBSP patterns — and clean under cancellation (no
-leaked threads, prefetch_depth=1 degenerates to thread-free sync reads)."""
+leaked threads, prefetch_depth=1 degenerates to thread-free sync reads).
+
+Dense chunks carry edge rows and the engine fills their tiles on the
+device: that fill equals the host's ``fill_local_batch`` /
+``fill_boundary_batch`` (bitwise for an INF zero, with or without parallel
+edges sharing a slot), and ``device_fills`` counts one per chunk."""
 import threading
 
 import numpy as np
@@ -15,8 +20,10 @@ from repro.core.engine import (
     source_init,
 )
 from repro.core.algorithms import pagerank
+from repro.core.graph import GraphTemplate
 from repro.gofs import GoFSStore
-from repro.gofs.prefetch import THREAD_PREFIX, SlicePrefetcher
+from repro.gofs.prefetch import THREAD_PREFIX, SlicePrefetcher, StagedChunk
+from repro.gopher import GopherSession
 
 from tests.conftest import TINY
 
@@ -79,6 +86,68 @@ def test_stream_chunks_match_bulk_load(env):
         assert np.array_equal(np.concatenate(got_b), btiles)
 
 
+def test_dense_chunks_carry_edge_rows_and_fill_tiles_lazily(env):
+    """A dense stream chunk ships its (n, E) rows; its host tiles fill on
+    first access only, and counting its bytes does not force them."""
+    tmpl, bg, store, weights, active = env
+    with store.load_blocked_stream(bg, "latency", chunk_instances=2) as pf:
+        ch = next(iter(pf))
+    rows = store.edge_attr_rows("latency", range(ch.start, ch.start + 2))
+    assert ch.edges.dtype == np.float32
+    assert np.array_equal(ch.edges, rows)
+    assert ch.nbytes == rows.nbytes == 2 * len(tmpl.src) * 4
+    assert ch._tiles is None and ch._btiles is None
+    assert np.array_equal(ch.tiles, bg.fill_local_batch(rows))
+    assert ch.tiles is ch.tiles  # filled once, kept
+
+
+def _graph_with(parallel: bool, seed: int = 11):
+    """A 48-vertex graph over 4 partitions; ``parallel`` repeats some
+    local and some cut edges, so two edges share a tile slot in both
+    tile sets."""
+    rng = np.random.default_rng(seed)
+    V = 48
+    pairs = {(int(a), int(b)) for a, b in rng.integers(0, V, (160, 2))
+             if a != b}
+    src, dst = map(np.array, zip(*sorted(pairs)))
+    assign = np.arange(V) % 4
+    if parallel:
+        local = np.flatnonzero(assign[src] == assign[dst])[:5]
+        cut = np.flatnonzero(assign[src] != assign[dst])[:5]
+        src = np.concatenate([src, src[local], src[cut]])
+        dst = np.concatenate([dst, dst[local], dst[cut]])
+    bg = build_blocked(GraphTemplate(num_vertices=V, src=src, dst=dst),
+                       assign, block_size=4)
+    w = rng.random((5, len(src))).astype(np.float32) + 0.5
+    return bg, w
+
+
+@pytest.mark.parametrize("zero", [np.inf, 0.0], ids=["inf", "zero"])
+@pytest.mark.parametrize("parallel", [False, True],
+                         ids=["unique", "shared"])
+def test_device_fill_matches_host_fill(zero, parallel):
+    """The engine's device fill of one chunk equals the host fill:
+    bitwise for ``.set`` (unique slots) and ``.min`` (INF zero, shared
+    slots), within 1e-6 for ``.add`` (0.0 zero, shared slots: the sum
+    order is the scatter's)."""
+    bg, w = _graph_with(parallel)
+    assert bg._local_slots_unique() is not parallel
+    assert bg._boundary_slots_unique() is not parallel
+    eng = TemporalEngine(bg)
+    tiles, btiles = eng._device_fill(
+        StagedChunk(0, len(w), edges=w, zero=zero, bg=bg))
+    want = (bg.fill_local_batch(w, zero=zero),
+            bg.fill_boundary_batch(w, zero=zero))
+    for got, ref in zip((tiles, btiles), want):
+        got = np.asarray(got)
+        assert got.shape == ref.shape and got.dtype == ref.dtype
+        if zero == 0.0 and parallel:
+            np.testing.assert_allclose(got, ref, rtol=0, atol=1e-6)
+        else:
+            assert np.array_equal(got, ref)
+    assert eng.device_fills == 1
+
+
 # ------------------------------------------------------- engine parity
 def test_async_staging_bitwise_parity_all_patterns(env):
     """TemporalEngine(staging="async") == sync staging, bit for bit, on
@@ -120,6 +189,45 @@ def test_async_parity_many_chunks_in_flight(env):
         b = async_.run(prog, w9, pattern=pattern)
         assert np.array_equal(a.values, b.values), pattern
         assert np.array_equal(a.final, b.final), pattern
+
+
+@pytest.mark.parametrize("pattern", ["sequential", "independent"])
+@pytest.mark.parametrize("chunk", [1, 2, 3])
+def test_dense_stream_fills_on_device_bitwise(env, pattern, chunk):
+    """A dense GoFS stream through ``_run_stream_many`` (edge rows, device
+    fill, ragged last chunk included) equals the sync host-filled run bit
+    for bit, with one device fill per chunk."""
+    tmpl, bg, store, weights, active = env
+    prog = min_plus_program("sssp", init=source_init(0))
+    a = TemporalEngine(bg).run(prog, weights, pattern=pattern)
+    eng = TemporalEngine(bg)
+    b = eng.run(prog, pattern=pattern, stream=store.load_blocked_stream(
+        bg, "latency", chunk_instances=chunk))
+    assert np.array_equal(a.values, b.values)
+    assert np.array_equal(a.stats["supersteps"], b.stats["supersteps"])
+    assert eng.device_fills == -(-store.num_timesteps() // chunk)
+
+
+def test_session_counts_device_fills(env, tiny_gofs):
+    """``last_run_report["device_fills"]``: one per chunk of a dense
+    stream pass; 0 for a sparse stream and for a resident
+    (``load_blocked``) run, which upload host tiles."""
+    tmpl, bg, store, weights, active = env
+    sess = GopherSession(GoFSStore(tiny_gofs))
+    chunks = -(-store.num_timesteps() // store.ipack)
+    dense = sess.run(sess.plan("sssp", source=0, staging="async"))
+    rep = sess.last_run_report
+    assert rep["device_fills"] == chunks
+    assert rep["staged_bytes"] == store.num_timesteps() * len(tmpl.src) * 4
+    sparse = sess.run(sess.plan("sssp", source=0, staging="async",
+                                layout="sparse"))
+    assert sess.last_run_report["device_fills"] == 0
+    assert np.array_equal(dense.engine.values, sparse.engine.values)
+    resident = GopherSession(GoFSStore(tiny_gofs), staging_cache_bytes=1e9)
+    r = resident.run(resident.plan("sssp", source=0))
+    assert resident.last_run_report["device_fills"] == 0
+    assert resident.last_run_report["staged_bytes"] > 0
+    assert np.array_equal(dense.engine.values, r.engine.values)
 
 
 def test_gofs_stream_engine_matches_sync(env):

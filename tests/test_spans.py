@@ -2,10 +2,11 @@
 opens where the staging, the engine and the service do their work, as
 often as that work happens, with a positive duration on the trace.
 
-* ``gofs.stage`` — one chunk's read and fill (prefetch pool, or the
-  caller when synchronous); ``gofs.wait`` — the caller blocked on it.
-* ``engine.put`` — a group of uploads; ``engine.build`` — the first call
-  of a new runner only; ``engine.gather`` — results back to the host.
+* ``gofs.stage`` — one chunk's read (prefetch pool, or the caller when
+  synchronous); ``gofs.wait`` — the caller blocked on it.
+* ``engine.put`` — a group of uploads (a streamed chunk's edge rows and
+  its device fill); ``engine.build`` — the first call of a new runner
+  only; ``engine.gather`` — results back to the host.
 * ``service.execute`` — one admitted batch on the serve thread.
 """
 import glob
@@ -68,7 +69,8 @@ def test_async_stream_pass_opens_each_span_where_its_work_is(env, tmp_path):
     first, spans = _traced(tmp_path / "first", one_pass)
     assert len(spans["gofs.stage"]) == chunks
     assert len(spans["gofs.wait"]) == chunks
-    assert len(spans["engine.put"]) >= 1
+    # each chunk's edge-row upload and device fill, one span a chunk
+    assert len(spans["engine.put"]) == chunks
     # every chunk has one shape: one runner, built on its first call
     assert len(spans["engine.build"]) == 1
     assert len(spans["engine.gather"]) >= 1
